@@ -18,12 +18,20 @@ One LAQ worker per device: the mesh is ``(n_devices, 1)`` over
     # error-feedback top-k compression (float wire)
     PYTHONPATH=src python examples/train_lm.py --steps 20 --strategy ef
 
+    # a profiler trace of rounds 10 to 15, for TensorBoard / XProf
+    PYTHONPATH=src python examples/train_lm.py --steps 20 --profile-dir /tmp/laq
+
 ``--preset`` picks a small dense LM (``smoke``, ``100m``); ``--arch`` any
 published architecture of ``repro.configs`` at its full config instead.
 
 Demonstrates the full production path: sharded data pipeline -> partial-auto
 shard_map LAQ train step (per-worker quantize + skip + explicit aggregation
 collective) -> optimizer -> checkpoint, with bits/rounds telemetry.
+
+Each round runs in a ``laq.round`` step annotation and its host read of the
+metrics in ``laq.readback``; on the profiler's one clock they sit beside the
+step's device operations, which carry the step's ``laq.*`` spans
+(``repro/core/spans.py``).
 """
 import argparse
 import os
@@ -64,6 +72,10 @@ PRESETS = {
 # error-feedback top-k compression (float wire, data-parallel mesh).
 STRATEGIES = ("gd", "qgd", "lag", "laq", "slaq", "wk2", "svrg", "ef")
 
+# the first and last round that --profile-dir traces (past the first
+# rounds, whose dispatch still warms up)
+PROFILE_ROUNDS = (10, 15)
+
 
 def build_strategy(name: str, bits: int,
                    wire_backend: str = "fused") -> StrategyConfig:
@@ -101,10 +113,11 @@ class TrainRun(NamedTuple):
 def train(cfg: ModelConfig, strategy: StrategyConfig, *, steps: int,
           batch: int, seq: int, lr: float, wire: str, accum: int = 1,
           seed: int = 0, n_devices: int | None = None,
-          log=print) -> TrainRun:
+          profile_dir: str | None = None, log=print) -> TrainRun:
     """Initialize from ``seed`` on a data-parallel mesh of the first
     ``n_devices`` devices (all by default) and run ``steps`` LAQ rounds of
-    the sharded train step on synthetic token batches."""
+    the sharded train step on synthetic token batches.  With
+    ``profile_dir``, rounds ``PROFILE_ROUNDS`` are traced there."""
     mesh = device_mesh(n_devices=n_devices)
     wa = ("data",)
     W = n_workers_of(mesh, wa)
@@ -134,15 +147,32 @@ def train(cfg: ModelConfig, strategy: StrategyConfig, *, steps: int,
     log(f"compiled the train step in {compile_s:.1f}s")
 
     history = []
-    for i in range(steps):
-        state, m = compiled(state, b)
-        history.append({"loss": float(m.loss), "uploads": int(m.uploads),
-                        "bits": float(m.bits)})
-        if i % 10 == 0 or i == steps - 1:
-            log(f"step {i:4d} loss={history[-1]['loss']:7.4f} "
-                f"uploads={history[-1]['uploads']} "
-                f"cum_bits={float(state.comm.total_bits):.3e}")
-        b = next(batches)
+    tracing = False
+    try:
+        for i in range(steps):
+            if profile_dir and i == PROFILE_ROUNDS[0]:
+                jax.profiler.start_trace(profile_dir)
+                tracing = True
+            with jax.profiler.StepTraceAnnotation("laq.round", step_num=i):
+                state, m = compiled(state, b)
+                with jax.profiler.TraceAnnotation("laq.readback"):
+                    history.append({"loss": float(m.loss),
+                                    "uploads": int(m.uploads),
+                                    "bits": float(m.bits)})
+            if tracing and i == PROFILE_ROUNDS[1]:
+                jax.profiler.stop_trace()
+                tracing = False
+                log(f"traced rounds {PROFILE_ROUNDS[0]}-{i} -> {profile_dir}")
+            if i % 10 == 0 or i == steps - 1:
+                log(f"step {i:4d} loss={history[-1]['loss']:7.4f} "
+                    f"uploads={history[-1]['uploads']} "
+                    f"cum_bits={float(state.comm.total_bits):.3e}")
+            b = next(batches)
+    finally:
+        if tracing:
+            jax.profiler.stop_trace()
+            log(f"traced rounds {PROFILE_ROUNDS[0]}-{steps - 1} -> "
+                f"{profile_dir}")
     return TrainRun(state, step, compiled, compile_s, history, mesh, W,
                     n_params)
 
@@ -167,6 +197,9 @@ def main():
                     choices=["fused", "reference"])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default="")
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a profiler trace of rounds %d to %d here"
+                         % PROFILE_ROUNDS)
     args = ap.parse_args()
 
     use_compile_cache()
@@ -176,7 +209,7 @@ def main():
         assert args.wire == "float", "--strategy ef requires --wire float"
     run = train(cfg, strategy, steps=args.steps, batch=args.batch,
                 seq=args.seq, lr=args.lr, wire=args.wire, accum=args.accum,
-                seed=args.seed)
+                seed=args.seed, profile_dir=args.profile_dir)
     if args.ckpt:
         save_checkpoint(args.ckpt, jax.device_get(run.state.params),
                         args.steps)

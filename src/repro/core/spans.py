@@ -1,0 +1,59 @@
+"""Named spans of the sharded LAQ step (``launch/train.py``).
+
+Each span is a ``jax.named_scope``: it writes its name into the
+``op_name`` metadata of every HLO instruction traced inside it and adds no
+operation, so a compiled step with spans is the same program as one
+without.  After XLA fuses, a fusion carries its root's ``op_name``.  An
+instruction belongs to the innermost ``laq.*`` name in its ``op_name``:
+the wire's quantize pass called from inside ``laq.aggregate`` is
+``laq.wire.quantize``.  A profiler trace of the step names each device
+operation by its HLO instruction, so the device time of a round splits by
+span (``bench/scopes.py``).
+
+Spans and the code each covers:
+
+* ``laq.fwd_bwd`` — the loss and its gradient at every evaluation point:
+  the current parameters, each microbatch of the fold, the SVRG anchor and
+  its correction, the WK2 stale side.
+* ``laq.lazy_rule`` — ``worker_update`` (criterion moments, skip decision,
+  clocks, bits), the history of the parameter motion, and the round's
+  counters and metrics.
+* ``laq.wire.quantize`` — the wire backends' radius and quantize
+  primitives (``core/wire.py``), the Pallas kernels and their operand
+  casts and pads included.
+* ``laq.wire.codec`` — the byte layout between the kernels' 1024-byte code
+  rows and the payload: the flatten and slice of the rows
+  (``kernels/``), their reshape to the leaf's shape, and
+  ``pack_codes_along_axis`` / ``unpack_codes_along_axis``.
+* ``laq.aggregate`` — the exchange, dequantize and masked sum of the packed
+  wire, the float wire's ``psum``, and the server aggregate's add and store.
+* ``laq.optimizer`` — ``optimizer.update`` and the parameter motion
+  ``||theta^{k+1} - theta^k||^2`` (XLA fuses its reduction into the update's
+  sweep; a fusion carries one span).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+
+FWD_BWD = "laq.fwd_bwd"
+LAZY_RULE = "laq.lazy_rule"
+WIRE_QUANTIZE = "laq.wire.quantize"
+WIRE_CODEC = "laq.wire.codec"
+AGGREGATE = "laq.aggregate"
+OPTIMIZER = "laq.optimizer"
+
+SPANS = (FWD_BWD, LAZY_RULE, WIRE_QUANTIZE, WIRE_CODEC, AGGREGATE, OPTIMIZER)
+
+
+def scoped(name: str):
+    """Decorator: the function runs inside span ``name`` (the scope is
+    made at each call, not when the function is defined)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap

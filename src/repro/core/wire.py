@@ -48,6 +48,7 @@ from .compressors import (_flat, _unflat, reference_sparse_quantize,
                           scatter_selection, select_support, sparse_grid)
 from .quantize import (dequantize_innovation, innovation, pack_codes,
                        quantize_codes, roundtrip_parts, tau, tree_sq_norm)
+from .spans import WIRE_CODEC, WIRE_QUANTIZE, scoped
 
 Pytree = object
 
@@ -89,6 +90,7 @@ class WireBackend:
                   with_payload: bool = False) -> WireRoundtrip:
         raise NotImplementedError
 
+    @scoped(WIRE_QUANTIZE)
     def leaf_absmax(self, g, qh):
         """Scalar ``|| g - qh ||_inf`` for ONE leaf (f32) — the radius
         pre-pass primitive (pass 1 of the two-pass pipeline, per leaf).
@@ -99,6 +101,7 @@ class WireBackend:
         return jnp.max(jnp.abs(g.astype(jnp.float32)
                                - qh.astype(jnp.float32))).astype(jnp.float32)
 
+    @scoped(WIRE_QUANTIZE)
     def leaf_quantize(self, g, qh, R, bits: int):
         """``(codes, delta)`` for one leaf at one static width — the
         send-side pass-2 sweep of the streamed sharded wire.  Shape
@@ -110,6 +113,7 @@ class WireBackend:
         delta = dequantize_innovation(codes, R, bits)
         return codes, delta
 
+    @scoped(WIRE_QUANTIZE)
     def leaf_quantize_adaptive(self, g, qh, R, grid, onehot, t_sel):
         """Traced-width variant of :meth:`leaf_quantize`: ``onehot``
         selects from the static ascending ``grid``, ``t_sel`` is
@@ -120,6 +124,7 @@ class WireBackend:
         delta = dequantize_dynamic(codes, R, t_sel)
         return codes, delta
 
+    @scoped(WIRE_QUANTIZE)
     def adaptive_roundtrip(self, grad: Pytree, qhat: Pytree, diff: Pytree,
                            R_tree: Pytree, grid, onehot):
         """Dynamic-width roundtrip ``(q_new, delta, err_sq, innovation_sq)``
@@ -163,9 +168,11 @@ class ReferenceWire(WireBackend):
 
     name = "reference"
 
+    @scoped(WIRE_QUANTIZE)
     def innovation(self, grad, qhat, per_leaf=False):
         return innovation(grad, qhat, per_leaf)
 
+    @scoped(WIRE_QUANTIZE)
     def roundtrip(self, grad, qhat, bits, per_leaf=False, with_payload=False):
         qints, R_tree, delta, q_new, R_max, err_sq = roundtrip_parts(
             grad, qhat, bits, per_leaf)
@@ -286,27 +293,34 @@ class FusedWire(WireBackend):
             return jax.default_backend() != "cpu"
         return self.lowering == "pallas"
 
+    @scoped(WIRE_QUANTIZE)
     def leaf_absmax(self, g, qh):
         if g.size and self._use_pallas():
             from repro.kernels import absmax
             return absmax(g, qh)
         return super().leaf_absmax(g, qh)
 
+    @scoped(WIRE_QUANTIZE)
     def leaf_quantize(self, g, qh, R, bits):
         if g.size and self._use_pallas():
             from repro.kernels import quantize_codes_fused
             codes, delta = quantize_codes_fused(g, qh, R, bits)
-            return codes.reshape(g.shape), delta.reshape(g.shape)
+            with jax.named_scope(WIRE_CODEC):
+                codes = codes.reshape(g.shape)
+            return codes, delta.reshape(g.shape)
         # the dense jnp expressions of the base class ARE the pass-2 math
         # (codes + delta, one sweep under jit) — bit-identical by sharing
         return super().leaf_quantize(g, qh, R, bits)
 
+    @scoped(WIRE_QUANTIZE)
     def leaf_quantize_adaptive(self, g, qh, R, grid, onehot, t_sel):
         if g.size and self._use_pallas():
             from repro.kernels import quantize_codes_adaptive
             codes, delta = quantize_codes_adaptive(g, qh, R, onehot,
                                                    tuple(grid))
-            return codes.reshape(g.shape), delta.reshape(g.shape)
+            with jax.named_scope(WIRE_CODEC):
+                codes = codes.reshape(g.shape)
+            return codes, delta.reshape(g.shape)
         return super().leaf_quantize_adaptive(g, qh, R, grid, onehot, t_sel)
 
     def _radii(self, g_leaves, q_leaves, per_leaf):
@@ -317,6 +331,7 @@ class FusedWire(WireBackend):
                               or [jnp.zeros((), jnp.float32)]))
         return [R for _ in g_leaves], R
 
+    @scoped(WIRE_QUANTIZE)
     def innovation(self, grad, qhat, per_leaf=False):
         """Radius via the pass-1 absmax reduction; the diff itself stays a
         lazy elementwise expression for downstream consumers (the adaptive
@@ -330,6 +345,7 @@ class FusedWire(WireBackend):
         R_tree = jax.tree_util.tree_unflatten(treedef, R_leaves)
         return diff, R_tree, R_max
 
+    @scoped(WIRE_QUANTIZE)
     def roundtrip(self, grad, qhat, bits, per_leaf=False, with_payload=False):
         assert bits in (1, 2, 4, 8), \
             f"fused wire backend covers the packed-width grid, got bits={bits}"
@@ -372,6 +388,7 @@ class FusedWire(WireBackend):
             R_max=R_max, err_sq=err_sq, innovation_sq=inn_sq,
             payload=payload if with_payload else None)
 
+    @scoped(WIRE_QUANTIZE)
     def adaptive_roundtrip(self, grad, qhat, diff, R_tree, grid, onehot):
         """Adaptive pass 2 as ONE sweep: the width-grid-unrolled fused
         kernel (kernels/quant_pack.py — one ``lax.switch`` arm per grid
@@ -572,6 +589,7 @@ def axis_packable(q, bits: int) -> bool:
     return cpb > 1 and q.ndim >= 1 and q.shape[-1] % cpb == 0
 
 
+@scoped(WIRE_CODEC)
 def pack_codes_along_axis(q, bits: int):
     """Pack 8/b codes per byte along the last dim (no-op layout for b=8 or
     an indivisible last dim: raw uint8 codes ship unpacked)."""
@@ -585,6 +603,7 @@ def pack_codes_along_axis(q, bits: int):
     return acc.astype(jnp.uint8)
 
 
+@scoped(WIRE_CODEC)
 def unpack_codes_along_axis(payload, bits: int, orig):
     """Inverse of :func:`pack_codes_along_axis`; ``orig`` supplies the
     unpacked shape (and whether packing applied at all)."""

@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import WIRE_CODEC
 from .quant_pack import (BLOCK, absmax_pallas, dequant_acc_pallas,
                          quantize_codes_adaptive_pallas, quantize_codes_pallas,
                          quantize_pack_adaptive_pallas, quantize_pack_pallas,
@@ -117,7 +118,9 @@ def quantize_codes_fused(grad, qhat, R, bits: int, *,
     g, qh, n = _pad_pair(grad, qhat)
     codes, delta = quantize_codes_pallas(
         g, qh, R.astype(jnp.float32).reshape(1), bits, interpret=interpret)
-    return codes[:n], delta[:n]
+    with jax.named_scope(WIRE_CODEC):
+        codes = codes[:n]
+    return codes, delta[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("grid", "interpret"))
@@ -133,7 +136,9 @@ def quantize_codes_adaptive(grad, qhat, R, onehot, grid: tuple, *,
     codes, delta = quantize_codes_adaptive_pallas(
         g, qh, R.astype(jnp.float32).reshape(1), sel, grid,
         interpret=interpret)
-    return codes[:n], delta[:n]
+    with jax.named_scope(WIRE_CODEC):
+        codes = codes[:n]
+    return codes, delta[:n]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))

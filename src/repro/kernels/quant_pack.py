@@ -65,6 +65,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.spans import WIRE_CODEC
+
 LANES = 128           # vector lanes; a packed chunk is LANES bytes wide
 ROW = 1024            # elements per row: 8 codes per byte at b=1 fill LANES
 ROWS = 32             # rows per grid step (a uint8 tile is 32 x 128)
@@ -367,7 +369,9 @@ def _codes_call(kernel, grad, qhat, interpret, *scalars):
         [jax.ShapeDtypeStruct((n // ROW, ROW), jnp.uint8),
          jax.ShapeDtypeStruct((n // ROW, ROW), jnp.float32)],
         interpret, _rows(grad), _rows(qhat), *scalars)
-    return codes.reshape(-1), delta.reshape(-1)
+    with jax.named_scope(WIRE_CODEC):
+        codes = codes.reshape(-1)
+    return codes, delta.reshape(-1)
 
 
 def quantize_codes_pallas(grad, qhat, R, bits: int, *, interpret: bool = True):

@@ -108,6 +108,7 @@ from repro.core.defense import DefenseState
 from repro.core.engine import (accumulate_loss_grads, apply_svrg_streaming,
                                participation_mask, stale_side_grads)
 from repro.core.quantize import tree_sq_norm
+from repro.core.spans import AGGREGATE, FWD_BWD, LAZY_RULE, OPTIMIZER
 from repro.core.strategy import (CommState, StrategyConfig, SvrgState,
                                  worker_update)
 from repro.core.wire import (get_backend, pack_codes_along_axis,
@@ -131,7 +132,6 @@ class StepMetrics(NamedTuple):
     loss: jax.Array
     uploads: jax.Array
     bits: jax.Array
-    grad_sq: jax.Array
 
 
 def _squeeze0(tree):
@@ -404,20 +404,22 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             same arithmetic AccumulatingSource runs in the simulated
             engine).  Probe mode (unrolled layers) unrolls the microbatch
             fold too so cost_analysis counts every pass."""
-            if microbatch == 1:
-                return jax.value_and_grad(loss_fn)(at_params, batch)
-            mb = jax.tree.map(
-                lambda x: x.reshape((microbatch, x.shape[0] // microbatch)
-                                    + x.shape[1:]), batch)
-            return accumulate_loss_grads(loss_fn, at_params, mb,
-                                         unroll=not cfg.scan_layers)
+            with jax.named_scope(FWD_BWD):
+                if microbatch == 1:
+                    return jax.value_and_grad(loss_fn)(at_params, batch)
+                mb = jax.tree.map(
+                    lambda x: x.reshape((microbatch, x.shape[0] // microbatch)
+                                        + x.shape[1:]), batch)
+                return accumulate_loss_grads(loss_fn, at_params, mb,
+                                             unroll=not cfg.scan_layers)
 
         loss, grads = loss_and_grads(params)
         # The wire reads the gradient as its dtype holds it.  Without the
         # barrier XLA may fuse a bf16 gradient's producer into the wire's
         # reductions and skip the rounding (excess precision), differently
         # for each wire backend, and the backends' radii part ways on TPU.
-        grads = jax.lax.optimization_barrier(grads)
+        with jax.named_scope(FWD_BWD):
+            grads = jax.lax.optimization_barrier(grads)
         lr_k = eta_at(strategy.eta_schedule, lr, comm.step)
 
         svrg_new = comm.svrg
@@ -426,9 +428,10 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             # the shared streaming-anchor stage (core/engine.py; the
             # simulated engine uses the exact-anchor variant): the anchor
             # backprop runs every step — svrg's inherent 2x compute
-            grads, corr, sv_new = apply_svrg_streaming(
-                _squeeze0(comm.svrg), params, grads,
-                lambda th: loss_and_grads(th)[1], comm.step, strategy)
+            with jax.named_scope(FWD_BWD):
+                grads, corr, sv_new = apply_svrg_streaming(
+                    _squeeze0(comm.svrg), params, grads,
+                    lambda th: loss_and_grads(th)[1], comm.step, strategy)
             svrg_new = _unsqueeze0(sv_new)
 
         grads_stale = None
@@ -436,77 +439,87 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             # the shared WK2 stage: the SAME batch at the stale iterate
             # (identical microbatching via loss_and_grads), svrg correction
             # applied to both sides so anchor and mu cancel
-            grads_stale = stale_side_grads(lambda th: loss_and_grads(th)[1],
-                                           lazy.theta_last, corr)
+            with jax.named_scope(FWD_BWD):
+                grads_stale = stale_side_grads(
+                    lambda th: loss_and_grads(th)[1], lazy.theta_last, corr)
 
-        avail = None
-        if strategy.participation != "full":
-            # this shard's slot of the replicated [W] cohort mask — the
-            # SAME draw the simulated engine makes (see module docstring
-            # for why the slot comes from the widx input, not axis_index)
-            avail = participation_mask(strategy, comm.step,
+        with jax.named_scope(LAZY_RULE):
+            avail = None
+            if strategy.participation != "full":
+                # this shard's slot of the replicated [W] cohort mask — the
+                # SAME draw the simulated engine makes (see module docstring
+                # for why the slot comes from the widx input, not axis_index)
+                avail = participation_mask(strategy, comm.step,
+                                           W)[jnp.squeeze(widx, 0)]
+
+            ckey = None
+            if strategy.compressor == "randk":
+                # this shard's slot of the round's [W] selection keys — the
+                # SAME draw the simulated engine makes (slot from the widx
+                # input, not axis_index; see the participation note above)
+                ckey = compressor_keys(strategy.compressor_seed, comm.step,
                                        W)[jnp.squeeze(widx, 0)]
 
-        ckey = None
-        if strategy.compressor == "randk":
-            # this shard's slot of the round's [W] selection keys — the SAME
-            # draw the simulated engine makes (slot from the widx input, not
-            # axis_index; see the participation note above)
-            ckey = compressor_keys(strategy.compressor_seed, comm.step,
-                                   W)[jnp.squeeze(widx, 0)]
-
-        wu = worker_update(grads, qhat, eps_hat_sq, clock, bits_spent,
-                           comm.theta_hist, lr_k, W, strategy, step=comm.step,
-                           lazy_m=lazy, R_anchor_m=R_anchor, params=params,
-                           grad_stale_m=grads_stale, avail_m=avail,
-                           error_m=error, ckey_m=ckey, defense_m=defense)
+            wu = worker_update(grads, qhat, eps_hat_sq, clock, bits_spent,
+                               comm.theta_hist, lr_k, W, strategy,
+                               step=comm.step, lazy_m=lazy,
+                               R_anchor_m=R_anchor, params=params,
+                               grad_stale_m=grads_stale, avail_m=avail,
+                               error_m=error, ckey_m=ckey, defense_m=defense)
         (delta_masked, qhat_new, eps_hat_sq_new, clock_new, uploaded,
          bits_m, width_m) = (wu.delta_masked, wu.qhat_new, wu.eps_hat_sq_new,
                              wu.clock_new, wu.uploaded, wu.bits_m, wu.width_m)
 
-        if wire == "float":
-            agg_delta = jax.tree.map(
-                functools.partial(jax.lax.psum, axis_name=wa), delta_masked)
-        else:
-            # a defense-rejected upload is masked off the wire exactly like
-            # a lazy skip (its bits_m still pay: the payload was sent)
-            skip = jnp.logical_not(wu.committed)
-            agg_delta, _ = _packed_aggregate(
-                grads, qhat, skip, strategy, wa, pspecs=grad_pspecs,
-                width=width_m if strategy.adaptive else None)
+        with jax.named_scope(AGGREGATE):
+            if wire == "float":
+                agg_delta = jax.tree.map(
+                    functools.partial(jax.lax.psum, axis_name=wa),
+                    delta_masked)
+            else:
+                # a defense-rejected upload is masked off the wire exactly
+                # like a lazy skip (its bits_m still pay: the payload was
+                # sent)
+                skip = jnp.logical_not(wu.committed)
+                agg_delta, _ = _packed_aggregate(
+                    grads, qhat, skip, strategy, wa, pspecs=grad_pspecs,
+                    width=width_m if strategy.adaptive else None)
 
-        agg = jax.tree.map(lambda a, d: a.astype(jnp.float32) + d,
-                           comm.server_agg, agg_delta)
-        agg_store = jax.tree.map(lambda a, s: a.astype(s.dtype), agg,
-                                 comm.server_agg)
-        new_params, new_opt = optimizer.update(agg, opt_state, params, lr_k)
-        dtheta_sq = tree_sq_norm(jax.tree.map(
-            lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-            new_params, params))
-
-        new_comm = CommState(
-            qhat=_unsqueeze0(qhat_new),
-            server_agg=agg_store,
-            eps_hat_sq=eps_hat_sq_new[None],
-            clocks=clock_new[None],
-            bits_spent=(bits_spent + bits_m)[None],
-            theta_hist=push_history(comm.theta_hist, dtheta_sq),
-            total_bits=comm.total_bits + jax.lax.psum(bits_m, wa),
-            total_uploads=comm.total_uploads
-            + jax.lax.psum(uploaded.astype(jnp.int32), wa),
-            step=comm.step + 1,
-            lazy=_unsqueeze0(wu.lazy_new),
-            R_anchor=wu.R_anchor_new[None],
-            svrg=svrg_new,
-            error=_unsqueeze0(wu.error_new),
-            defense=_unsqueeze0(wu.defense_new),
-        )
-        metrics = StepMetrics(
-            loss=jax.lax.psum(loss, wa),
-            uploads=jax.lax.psum(uploaded.astype(jnp.int32), wa),
-            bits=jax.lax.psum(bits_m, wa),
-            grad_sq=tree_sq_norm(agg),
-        )
+            agg = jax.tree.map(lambda a, d: a.astype(jnp.float32) + d,
+                               comm.server_agg, agg_delta)
+            agg_store = jax.tree.map(lambda a, s: a.astype(s.dtype), agg,
+                                     comm.server_agg)
+        with jax.named_scope(OPTIMIZER):
+            new_params, new_opt = optimizer.update(agg, opt_state, params,
+                                                   lr_k)
+            # the parameter motion the lazy rule keeps: XLA fuses its
+            # reduction into the update's sweep, and a fusion carries one
+            # span, so it is the optimizer's
+            dtheta_sq = tree_sq_norm(jax.tree.map(
+                lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
+                new_params, params))
+        with jax.named_scope(LAZY_RULE):
+            new_comm = CommState(
+                qhat=_unsqueeze0(qhat_new),
+                server_agg=agg_store,
+                eps_hat_sq=eps_hat_sq_new[None],
+                clocks=clock_new[None],
+                bits_spent=(bits_spent + bits_m)[None],
+                theta_hist=push_history(comm.theta_hist, dtheta_sq),
+                total_bits=comm.total_bits + jax.lax.psum(bits_m, wa),
+                total_uploads=comm.total_uploads
+                + jax.lax.psum(uploaded.astype(jnp.int32), wa),
+                step=comm.step + 1,
+                lazy=_unsqueeze0(wu.lazy_new),
+                R_anchor=wu.R_anchor_new[None],
+                svrg=svrg_new,
+                error=_unsqueeze0(wu.error_new),
+                defense=_unsqueeze0(wu.defense_new),
+            )
+            metrics = StepMetrics(
+                loss=jax.lax.psum(loss, wa),
+                uploads=jax.lax.psum(uploaded.astype(jnp.int32), wa),
+                bits=jax.lax.psum(bits_m, wa),
+            )
         return new_params, new_opt, new_comm, metrics
 
     # --- partial-auto shard_map: manual over worker axes, auto over model ---
@@ -538,12 +551,14 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             out_specs=(jax.tree.map(lambda _: P(), state.params),
                        jax.tree.map(lambda _: P(), state.opt_state),
                        specs_comm,
-                       StepMetrics(P(), P(), P(), P())),
+                       StepMetrics(P(), P(), P())),
             axis_names=manual, check_vma=False)
         new_params, new_opt, new_comm, metrics = sm(
             state.params, state.opt_state, comm, batch,
             jnp.arange(W, dtype=jnp.int32))
-        return TrainState(new_params, new_opt, new_comm, state.step + 1), metrics
+        with jax.named_scope(LAZY_RULE):
+            new_step = state.step + 1
+        return TrainState(new_params, new_opt, new_comm, new_step), metrics
 
     # introspection: the wire backend the sharded step runs
     step.wire_backend = get_backend(strategy.wire_backend).name
