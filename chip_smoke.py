@@ -86,7 +86,6 @@ def kernels_phase(seed, shapes):
         absmax,
         dequant_acc,
         quantize_codes_adaptive,
-        quantize_codes_fused,
         quantize_pack,
         quantize_pack_adaptive,
         quantize_pack_fused,
@@ -124,12 +123,8 @@ def kernels_phase(seed, shapes):
 
             pk, dl = quantize_pack(g, qh, R, bits)
             rpk, rdl = jax.jit(ref.quantize_pack_ref, static_argnums=2)(diff, R, bits)
-            bitwise(f"payload b={bits} codes", pk, rpk)
+            bitwise(f"payload b={bits} codes", pk.reshape(-1), rpk)
             bitwise(f"payload b={bits} delta", dl, rdl[:n])
-
-            codes, dl = quantize_codes_fused(g, qh, R, bits)
-            bitwise(f"codes sweep b={bits} codes", codes, _codes(rpk, bits)[:n])
-            bitwise(f"codes sweep b={bits} delta", dl, rdl[:n])
 
             W = 2
             pks = jnp.stack([pk, jnp.roll(pk, 1)])
@@ -159,9 +154,20 @@ def kernels_phase(seed, shapes):
             close(f"adaptive b={bits} err_sq", esq, resq)
             close(f"adaptive b={bits} innovation_sq", isq, risq)
             codes, dl = quantize_codes_adaptive(g, qh, R, onehot, grid)
-            fcodes, fdl = quantize_codes_fused(g, qh, R, bits)
-            bitwise(f"adaptive codes sweep b={bits} codes", codes, fcodes)
-            bitwise(f"adaptive codes sweep b={bits} delta", dl, fdl)
+            rpk, rdl = jax.jit(ref.quantize_pack_ref, static_argnums=2)(
+                diff, R, bits
+            )
+            bitwise(
+                f"adaptive codes sweep b={bits} codes", codes, _codes(rpk, bits)[:n]
+            )
+            bitwise(f"adaptive codes sweep b={bits} delta", dl, rdl[:n])
+            fpk, fdl = quantize_pack(g, qh, R, bits)
+            bitwise(
+                f"adaptive codes sweep b={bits} codes vs payload kernel",
+                codes,
+                _codes(fpk.reshape(-1), bits)[:n],
+            )
+            bitwise(f"adaptive codes sweep b={bits} delta vs payload kernel", dl, fdl)
         del g, qh, diff
 
 

@@ -21,12 +21,15 @@ Spans and the code each covers:
 * ``laq.wire.quantize`` — the wire backends' radius and quantize
   primitives (``core/wire.py``), the Pallas kernels and their operand
   casts and pads included.
-* ``laq.wire.codec`` — the byte layout between the kernels' 1024-byte code
-  rows and the payload: the flatten and slice of the rows
-  (``kernels/``), their reshape to the leaf's shape, and
-  ``pack_codes_along_axis`` / ``unpack_codes_along_axis``.
-* ``laq.aggregate`` — the exchange, dequantize and masked sum of the packed
-  wire, the float wire's ``psum``, and the server aggregate's add and store.
+* ``laq.wire.codec`` — the byte layout between the kernels and the leaf.
+  On the whole-leaf wire: the flattening and packing of the codes where
+  no kernel packs them (``pack_flat``), and the slice and reshape of the
+  decoded f32 sum to the leaf.  On the axis codec: the flatten and slice
+  of the kernels' code rows (``kernels/``), their reshape to the leaf's
+  shape, and ``pack_codes_along_axis`` / ``unpack_codes_along_axis``.
+* ``laq.aggregate`` — the exchange, the decode kernel (``dequant_acc``)
+  or the dequantize and masked sum of the packed wire, the float wire's
+  ``psum``, and the server aggregate's add and store.
 * ``laq.optimizer`` — ``optimizer.update`` and the parameter motion
   ``||theta^{k+1} - theta^k||^2`` (XLA fuses its reduction into the update's
   sweep; a fusion carries one span).

@@ -53,6 +53,18 @@ from .spans import WIRE_CODEC, WIRE_QUANTIZE, scoped
 Pytree = object
 
 
+def pack_flat(codes, bits: int):
+    """The flat payload of one leaf's codes: flattened, padded with
+    midpoint codes to whole bytes, packed 8/b per byte
+    (``docs/wire-format.md`` section 3)."""
+    flat = codes.reshape(-1)
+    pad = (-flat.shape[0]) % (8 // bits)
+    if pad:
+        flat = jnp.concatenate([flat, jnp.full((pad,), (2 ** bits) // 2,
+                                               jnp.uint8)])
+    return pack_codes(flat, bits)
+
+
 class WireRoundtrip(NamedTuple):
     """Everything one round's quantize step produces for one worker."""
     q_new: Pytree           # Q_m(theta^k) = qhat + delta
@@ -69,9 +81,10 @@ class WireRoundtrip(NamedTuple):
 class WireBackend:
     """Interface: radius reduction, quantize roundtrip, server dequant-acc.
 
-    The per-LEAF primitives (``leaf_absmax`` / ``leaf_quantize`` /
-    ``leaf_quantize_adaptive``) are the streamed sharded wire's hot loop
-    (launch/train.py ``_packed_aggregate`` touches one leaf at a time).
+    The per-LEAF primitives (``leaf_absmax`` / ``leaf_quantize_packed`` /
+    ``leaf_quantize`` / ``leaf_quantize_adaptive``) are the streamed
+    sharded wire's hot loop (launch/train.py ``_packed_aggregate`` touches
+    one leaf at a time).
     Their base-class bodies below ARE the reference expressions — verbatim
     :mod:`repro.core.quantize` / :mod:`repro.core.adaptive` calls — so every
     backend inherits bit-identical wire content by code sharing; subclasses
@@ -104,7 +117,7 @@ class WireBackend:
     @scoped(WIRE_QUANTIZE)
     def leaf_quantize(self, g, qh, R, bits: int):
         """``(codes, delta)`` for one leaf at one static width — the
-        send-side pass-2 sweep of the streamed sharded wire.  Shape
+        send-side pass-2 sweep of the sharded wire's axis codec.  Shape
         preserving (codes uint8, delta f32, both leaf-shaped): the
         axis-packed payload codec downstream packs along the leaf's last
         dim, so the codes must keep the leaf shape."""
@@ -112,6 +125,20 @@ class WireBackend:
         codes = quantize_codes(d, R, bits)
         delta = dequantize_innovation(codes, R, bits)
         return codes, delta
+
+    @scoped(WIRE_QUANTIZE)
+    def leaf_quantize_packed(self, g, qh, R, bits: int):
+        """``(payload, delta)`` for one leaf at one static width — the
+        send-side pass-2 sweep of the whole-leaf sharded wire: the codes
+        leave packed in the flat layout (:func:`pack_flat`), and
+        ``dequant_acc`` decodes the payloads stacked over workers.  The
+        payload's padding is the backend's own (the fused kernel's is
+        BLOCK-padded rows), its byte semantics are shared; ``delta`` is f32
+        and leaf-shaped."""
+        codes, delta = self.leaf_quantize(g, qh, R, bits)
+        with jax.named_scope(WIRE_CODEC):
+            payload = pack_flat(codes, bits)
+        return payload, delta
 
     @scoped(WIRE_QUANTIZE)
     def leaf_quantize_adaptive(self, g, qh, R, grid, onehot, t_sel):
@@ -179,18 +206,8 @@ class ReferenceWire(WireBackend):
         innovation_sq = tree_sq_norm(delta)
         payload = None
         if with_payload:
-            cpb = 8 // bits
-            mid = jnp.uint8((2 ** bits) // 2)
-
-            def leaf_payload(q):
-                flat = q.reshape(-1)
-                pad = (-flat.shape[0]) % cpb
-                if pad:
-                    flat = jnp.concatenate([flat, jnp.full((pad,), mid,
-                                                           jnp.uint8)])
-                return pack_codes(flat, bits)
-
-            payload = [leaf_payload(q) for q in jax.tree_util.tree_leaves(qints)]
+            payload = [pack_flat(q, bits)
+                       for q in jax.tree_util.tree_leaves(qints)]
         return WireRoundtrip(q_new, delta, R_tree, R_max, err_sq,
                              innovation_sq, payload)
 
@@ -214,7 +231,6 @@ def _fused_leaf_jnp(g, qh, R, bits, with_payload):
     expressions AND the identical reduction tree, so wire content and
     moments are bit-identical on CPU.
     """
-    n = g.size
     gf = g.reshape(-1).astype(jnp.float32)
     qf = qh.reshape(-1).astype(jnp.float32)
     d = gf - qf
@@ -229,15 +245,7 @@ def _fused_leaf_jnp(g, qh, R, bits, with_payload):
     err = gf - qn
     err_sq = jnp.sum(err * err)
     inn_sq = jnp.sum(delta * delta)
-    payload = None
-    if with_payload:
-        cpb = 8 // bits
-        pad = (-n) % cpb
-        qp = q
-        if pad:
-            qp = jnp.concatenate(
-                [q, jnp.full((pad,), (levels + 1) // 2, jnp.uint8)])
-        payload = pack_codes(qp, bits)
+    payload = pack_flat(q, bits) if with_payload else None
     return delta, qn, err_sq, inn_sq, payload
 
 
@@ -301,16 +309,12 @@ class FusedWire(WireBackend):
         return super().leaf_absmax(g, qh)
 
     @scoped(WIRE_QUANTIZE)
-    def leaf_quantize(self, g, qh, R, bits):
+    def leaf_quantize_packed(self, g, qh, R, bits):
         if g.size and self._use_pallas():
-            from repro.kernels import quantize_codes_fused
-            codes, delta = quantize_codes_fused(g, qh, R, bits)
-            with jax.named_scope(WIRE_CODEC):
-                codes = codes.reshape(g.shape)
-            return codes, delta.reshape(g.shape)
-        # the dense jnp expressions of the base class ARE the pass-2 math
-        # (codes + delta, one sweep under jit) — bit-identical by sharing
-        return super().leaf_quantize(g, qh, R, bits)
+            from repro.kernels import quantize_pack
+            payload, delta = quantize_pack(g, qh, R, bits)
+            return payload, delta.reshape(g.shape)
+        return super().leaf_quantize_packed(g, qh, R, bits)
 
     @scoped(WIRE_QUANTIZE)
     def leaf_quantize_adaptive(self, g, qh, R, grid, onehot, t_sel):
@@ -433,7 +437,7 @@ class FusedWire(WireBackend):
                 err_sq, inn_sq)
 
     def dequant_acc(self, packed, R, keep, bits, n, acc=None):
-        if self._use_pallas():
+        if n and self._use_pallas():
             from repro.kernels import dequant_acc
             return dequant_acc(packed, R, keep, bits, n, acc)
         from repro.kernels.ref import dequant_acc_ref
@@ -528,15 +532,7 @@ def sparse_roundtrip(backend, grad: Pytree, qhat: Pytree, bits: int, k: int,
     err = sel.vals - deq
     err_sq = jnp.sum(err * err)
     inn_sq = jnp.sum(delta_flat * delta_flat)
-    payload = None
-    if with_payload:
-        cpb = 8 // bits
-        mid = jnp.uint8((2 ** bits) // 2)
-        pad = (-codes.shape[0]) % cpb
-        cp = codes
-        if pad:
-            cp = jnp.concatenate([codes, jnp.full((pad,), mid, jnp.uint8)])
-        payload = pack_codes(cp, bits)
+    payload = pack_flat(codes, bits) if with_payload else None
     return SparseRoundtrip(q_new=_unflat(qn_flat, meta),
                            delta=_unflat(delta_flat, meta),
                            lo=lo, R=hi, err_sq=err_sq, innovation_sq=inn_sq,

@@ -14,13 +14,14 @@ where interpret-mode Pallas would serialize the grid.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.spans import WIRE_CODEC
 from .quant_pack import (BLOCK, absmax_pallas, dequant_acc_pallas,
-                         quantize_codes_adaptive_pallas, quantize_codes_pallas,
+                         quantize_codes_adaptive_pallas,
                          quantize_pack_adaptive_pallas, quantize_pack_pallas,
                          quantize_pack_payload_pallas,
                          sparse_quant_pack_pallas)
@@ -102,32 +103,18 @@ def quantize_pack_adaptive(grad, qhat, R, onehot, grid: tuple, *,
     return packed, delta[:n], q_new[:n], jnp.sum(err_p), jnp.sum(inn_p)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def quantize_codes_fused(grad, qhat, R, bits: int, *,
-                         interpret: bool | None = None):
-    """Pass 2 for the streamed sharded wire: codes + delta in one sweep,
-    codes left UNPACKED (the sharded wire packs along the leaf's last dim
-    itself — core/wire.py pack_codes_along_axis).
+@functools.partial(jax.jit, static_argnames=("grid", "interpret"))
+def quantize_codes_adaptive(grad, qhat, R, onehot, grid: tuple, *,
+                            interpret: bool | None = None):
+    """Pass 2 for the adaptive sharded wire's axis codec: codes + delta in
+    one sweep at the width ``onehot`` selects from the static ``grid`` (one
+    ``lax.switch`` arm per width), codes left UNPACKED (the axis codec
+    packs along the leaf's last dim itself — core/wire.py
+    pack_codes_along_axis).
 
     grad/qhat f32 (any shape, flattened), R scalar.  Returns ``(codes uint8
     [n], delta f32 [n])`` sliced to the real length (callers reshape back
     to the leaf shape).
-    """
-    if interpret is None:
-        interpret = _on_cpu()
-    g, qh, n = _pad_pair(grad, qhat)
-    codes, delta = quantize_codes_pallas(
-        g, qh, R.astype(jnp.float32).reshape(1), bits, interpret=interpret)
-    with jax.named_scope(WIRE_CODEC):
-        codes = codes[:n]
-    return codes, delta[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("grid", "interpret"))
-def quantize_codes_adaptive(grad, qhat, R, onehot, grid: tuple, *,
-                            interpret: bool | None = None):
-    """Traced-width variant of :func:`quantize_codes_fused` (``onehot``
-    selects from the static ``grid`` via one ``lax.switch`` arm per width).
     """
     if interpret is None:
         interpret = _on_cpu()
@@ -143,12 +130,15 @@ def quantize_codes_adaptive(grad, qhat, R, onehot, grid: tuple, *,
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def quantize_pack(grad, qhat, R, bits: int, *, interpret: bool | None = None):
-    """Flat leaf quantize+pack. grad/qhat f32 [n], R scalar.
+    """Flat leaf quantize+pack. grad/qhat (any shape, flattened), R scalar.
 
-    Returns (packed uint8 [ceil(n/blk)*blk*bits/8], delta f32 [n]).
-    The payload-only kernel: no q_new/moment outputs, so payload-only
-    callers don't pay their VMEM writes (use quantize_pack_fused when the
-    criterion moments are wanted too).
+    Returns ``(packed uint8 [ceil(n/blk)*blk/ROW, ROW*bits/8], delta f32
+    [n])``: the payload in the kernel's rows of ``ROW`` codes, which
+    :func:`dequant_acc` takes stacked over workers as they are (their bytes
+    in order are the flat little-end-first payload).  The payload-only
+    kernel: no q_new/moment outputs, so payload-only callers don't pay
+    their VMEM writes (use quantize_pack_fused when the criterion moments
+    are wanted too).
     """
     if interpret is None:
         interpret = _on_cpu()
@@ -184,12 +174,14 @@ def dequant_acc(packed, R, keep, bits: int, n: int, acc=None, *,
                 interpret: bool | None = None):
     """Server-side unpack+dequant+accumulate over the worker dim.
 
-    ``acc`` (optional f32 [n], e.g. the server aggregate) is folded into the
-    same pass: out = acc + sum_w keep_w * delta_w.
+    ``packed``: uint8 [W, nbytes], or [W, rows, ROW*bits/8] as
+    :func:`quantize_pack` emits them, BLOCK-padded either way.  Returns
+    f32 [n].  ``acc`` (optional f32 [n], e.g. the server aggregate) is
+    folded into the same pass: out = acc + sum_w keep_w * delta_w.
     """
     if interpret is None:
         interpret = _on_cpu()
-    n_padded = packed.shape[1] * 8 // bits
+    n_padded = math.prod(packed.shape[1:]) * 8 // bits
     acc_padded = None
     if acc is not None:
         acc_padded, _ = _pad_to_block(acc.astype(jnp.float32).reshape(-1))
@@ -197,4 +189,5 @@ def dequant_acc(packed, R, keep, bits: int, n: int, acc=None, *,
     out = dequant_acc_pallas(packed, R.astype(jnp.float32),
                              keep.astype(jnp.float32), bits, n_padded,
                              acc_padded, interpret=interpret)
-    return out[:n]
+    with jax.named_scope(WIRE_CODEC):
+        return out.reshape(-1)[:n]

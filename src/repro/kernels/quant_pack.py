@@ -59,6 +59,7 @@ TPU v5e at real leaf widths.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -267,7 +268,12 @@ def quantize_pack_payload_pallas(grad, qhat, R, bits: int, *,
                                  interpret: bool = True):
     """Payload-only variant of the pass-2 kernel: packed codes + delta, no
     q_new/moment outputs — for callers that only ship the wire payload and
-    should not pay the extra VMEM writes (benchmarks, the roundtrip tests).
+    should not pay the extra VMEM writes (the sharded step's whole-leaf
+    wire, benchmarks, the roundtrip tests).
+
+    Returns ``(packed uint8 [n // ROW, ROW*bits/8], delta f32 [n])``: the
+    payload stays in the kernel's rows, since on the TPU a 1-D uint8
+    reshape of them is a relayout of its own.
     """
     n = grad.shape[0]
     assert bits in (1, 2, 4, 8), bits
@@ -278,7 +284,7 @@ def quantize_pack_payload_pallas(grad, qhat, R, bits: int, *,
         [jax.ShapeDtypeStruct((n // ROW, ROW * bits // 8), jnp.uint8),
          jax.ShapeDtypeStruct((n // ROW, ROW), jnp.float32)],
         interpret, _rows(grad), _rows(qhat), R)
-    return packed.reshape(-1), delta.reshape(-1)
+    return packed, delta.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -344,45 +350,14 @@ def quantize_pack_adaptive_pallas(grad, qhat, R, sel, grid, n_valid: int, *,
 
 
 # ---------------------------------------------------------------------------
-# Sharded-wire pass 2: quantize emitting UNPACKED codes + delta in one
-# sweep.  The packed collective wire packs along the leaf's LAST dim
-# (core/wire.py pack_codes_along_axis — flattening a model-sharded leaf
-# would force a GSPMD regather), so the kernel leaves packing to that
-# shared axis codec and just fuses the code/delta math; the caller reshapes
-# the flat outputs back to the leaf shape.  Fixed-width and width-switched
-# (adaptive) variants.
+# Adaptive axis-codec pass 2: quantize at the traced width emitting UNPACKED
+# codes + delta in one sweep.  The adaptive sharded wire keeps the axis
+# codec, which packs along the leaf's LAST dim (core/wire.py
+# pack_codes_along_axis), so the kernel leaves packing to that codec and
+# just fuses the code/delta math; the caller reshapes the flat outputs back
+# to the leaf shape.  The fixed-width wire ships the payload kernel's
+# packed rows instead.
 # ---------------------------------------------------------------------------
-
-def _quantize_codes_kernel(bits, g_ref, qh_ref, R_ref, codes_ref, delta_ref):
-    R = R_ref[0]
-    qf = _quant_codes(g_ref[...] - qh_ref[...], R, bits)
-    codes_ref[...] = _to_u8(qf)
-    delta_ref[...] = _dequant(qf, R, bits)
-
-
-def _codes_call(kernel, grad, qhat, interpret, *scalars):
-    n = grad.shape[0]
-    codes, delta = _call(
-        kernel, n,
-        [_rows_spec(ROW), _rows_spec(ROW)] + [_smem() for _ in scalars],
-        [_rows_spec(ROW), _rows_spec(ROW)],
-        [jax.ShapeDtypeStruct((n // ROW, ROW), jnp.uint8),
-         jax.ShapeDtypeStruct((n // ROW, ROW), jnp.float32)],
-        interpret, _rows(grad), _rows(qhat), *scalars)
-    with jax.named_scope(WIRE_CODEC):
-        codes = codes.reshape(-1)
-    return codes, delta.reshape(-1)
-
-
-def quantize_codes_pallas(grad, qhat, R, bits: int, *, interpret: bool = True):
-    """grad, qhat: flat f32 [n] (n % BLOCK == 0), R: f32 [1].
-
-    Returns ``(codes uint8 [n], delta f32 [n])`` — the sharded packed wire's
-    send-side sweep (codes stay unpacked for the axis codec)."""
-    assert bits in (1, 2, 4, 8), bits
-    return _codes_call(functools.partial(_quantize_codes_kernel, bits),
-                       grad, qhat, interpret, R)
-
 
 def _quantize_codes_adaptive_kernel(grid, g_ref, qh_ref, R_ref, sel_ref,
                                     codes_ref, delta_ref):
@@ -395,13 +370,25 @@ def _quantize_codes_adaptive_kernel(grid, g_ref, qh_ref, R_ref, sel_ref,
 
 def quantize_codes_adaptive_pallas(grad, qhat, R, sel, grid, *,
                                    interpret: bool = True):
-    """Width-switched variant of :func:`quantize_codes_pallas` (``sel``:
-    int32 [1] index into ``grid``)."""
+    """grad, qhat: flat f32 [n] (n % BLOCK == 0), R: f32 [1], sel: int32
+    [1] index into ``grid``.
+
+    Returns ``(codes uint8 [n], delta f32 [n])`` at the selected width —
+    the adaptive sharded wire's send-side sweep (codes stay unpacked for
+    the axis codec)."""
     grid = tuple(grid)
     assert all(b in (1, 2, 4, 8) for b in grid), grid
-    return _codes_call(
-        functools.partial(_quantize_codes_adaptive_kernel, grid),
-        grad, qhat, interpret, R, sel)
+    n = grad.shape[0]
+    codes, delta = _call(
+        functools.partial(_quantize_codes_adaptive_kernel, grid), n,
+        [_rows_spec(ROW), _rows_spec(ROW), _smem(), _smem()],
+        [_rows_spec(ROW), _rows_spec(ROW)],
+        [jax.ShapeDtypeStruct((n // ROW, ROW), jnp.uint8),
+         jax.ShapeDtypeStruct((n // ROW, ROW), jnp.float32)],
+        interpret, _rows(grad), _rows(qhat), R, sel)
+    with jax.named_scope(WIRE_CODEC):
+        codes = codes.reshape(-1)
+    return codes, delta.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -476,13 +463,16 @@ def _dequant_acc_kernel(bits, W, has_acc, *refs):
 
 def dequant_acc_pallas(packed, R, keep, bits: int, n: int, acc=None, *,
                        interpret: bool = True):
-    """packed: [W, n*bits/8] uint8; R, keep: [W] f32 -> f32 [n] (summed).
+    """packed: uint8 [W, n*bits/8], or the send kernel's rows stacked,
+    [W, n // ROW, ROW*bits/8]; R, keep: [W] f32 -> f32 [n // ROW, ROW]
+    (summed, in rows).
 
     ``acc`` (optional f32 [n], e.g. the server aggregate) is folded into the
     same pass: out = acc + sum_w delta_w.
     """
     assert bits in (1, 2, 4, 8), bits
-    W, nbytes = packed.shape
+    W = packed.shape[0]
+    nbytes = math.prod(packed.shape[1:])
     assert nbytes * 8 // bits == n, (nbytes, bits, n)
     in_specs = [pl.BlockSpec((W, ROWS, ROW * bits // 8),
                              lambda i: (0, i, 0)),
@@ -496,4 +486,4 @@ def dequant_acc_pallas(packed, R, keep, bits: int, n: int, acc=None, *,
                 in_specs, _rows_spec(ROW),
                 jax.ShapeDtypeStruct((n // ROW, ROW), jnp.float32),
                 interpret, *args)
-    return out.reshape(-1)
+    return out

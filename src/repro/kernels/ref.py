@@ -107,11 +107,14 @@ def quantize_pack_adaptive_ref(grad: jnp.ndarray, qhat: jnp.ndarray,
 
 def dequant_acc_ref(packed: jnp.ndarray, R: jnp.ndarray, keep: jnp.ndarray,
                     bits: int, n: int, acc: jnp.ndarray = None):
-    """packed [W, n*bits/8] uint8, R [W], keep [W] -> sum_w delta_w, f32 [n].
+    """packed [W, n*bits/8] uint8 (or [W, rows, ROW*bits/8], the payload
+    kernel's rows, taken flattened), R [W], keep [W] -> sum_w delta_w,
+    f32 [n].
 
     ``acc`` (optional f32 [n]) is the server aggregate folded into the sum.
     """
     assert bits in (1, 2, 4, 8)
+    packed = packed.reshape(packed.shape[0], -1)
     t = 1.0 / (2.0 ** bits - 1.0)
     if bits < 8:
         mask = (1 << bits) - 1

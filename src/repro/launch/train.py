@@ -21,10 +21,13 @@ skip criterion, and the aggregation collective is explicit:
 Packed wire format (per worker, per round):
 
 * **fixed-bit** (``bit_schedule`` None/constant, width b in {2, 4, 8}) —
-  per leaf, codes packed little-end-first at 8/b codes per byte when the
-  leaf's last dim divides 8/b (odd last dims ship raw uint8 codes), plus two
+  per leaf, codes packed little-end-first at 8/b codes per byte, plus two
   sidecars exchanged once per round: the radius ``R`` (f32 per leaf for
-  ``per_leaf_radius``, else one global f32) and the skip-mask bit.
+  ``per_leaf_radius``, else one global f32) and the skip-mask bit.  Where
+  every leaf is whole on its device (no mesh axis left to GSPMD) the
+  payload is the flattened leaf's, as the send kernel packs it, and the
+  decode kernel sums it; elsewhere codes are packed along the leaf's last
+  dim when it divides 8/b (odd last dims ship raw uint8 codes).
 * **adaptive** (``bit_schedule`` radius/budget, core/adaptive.py) — each
   worker additionally announces its selected width ``b_m^k`` as a third
   sidecar, and every receiver decodes with the sender's tau(b_m^k).  The
@@ -95,6 +98,7 @@ in ``docs/wire-format.md``.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import NamedTuple
 
 import jax
@@ -108,7 +112,8 @@ from repro.core.defense import DefenseState
 from repro.core.engine import (accumulate_loss_grads, apply_svrg_streaming,
                                participation_mask, stale_side_grads)
 from repro.core.quantize import tree_sq_norm
-from repro.core.spans import AGGREGATE, FWD_BWD, LAZY_RULE, OPTIMIZER
+from repro.core.spans import (AGGREGATE, FWD_BWD, LAZY_RULE, OPTIMIZER,
+                              WIRE_CODEC)
 from repro.core.strategy import (CommState, StrategyConfig, SvrgState,
                                  worker_update)
 from repro.core.wire import (get_backend, pack_codes_along_axis,
@@ -164,7 +169,7 @@ def exchange_mode(n_workers: int) -> str:
 
 
 def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
-                      worker_axes, pspecs=None, width=None):
+                      worker_axes, pspecs=None, width=None, flat=False):
     """The packed-uint8 wire, **streamed one leaf at a time**: per leaf,
     innovation -> quantize -> pack -> exchange -> dequantize -> masked sum
     (plus that leaf's local ``q_new`` reconstruction) before the next leaf
@@ -189,10 +194,17 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
     switches on the adaptive wire: codes are produced at the selected width,
     the buffer is provisioned at max(grid), and the width rides along as a
     sidecar so receivers decode with the sender's tau (see module docstring).
+
+    ``flat`` (fixed width, every leaf whole on its device) switches on the
+    whole-leaf wire: the backend's ``leaf_quantize_packed`` payload ships
+    as it is, and ``dequant_acc`` unpacks, dequantizes and masked-sums the
+    workers' payloads in one pass; no leaf-shaped code array exists.
+    Otherwise the axis-packed codec below keeps each leaf's shape.
     """
     from repro.models.layers import maybe_constrain
     per_leaf = strategy.per_leaf_radius
     adaptive = width is not None
+    assert not (flat and adaptive), "the whole-leaf wire has a fixed width"
     if adaptive:
         grid = strategy.bit_schedule.grid
         onehot = (jnp.asarray(grid, jnp.float32) == width).astype(jnp.float32)
@@ -211,12 +223,12 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
     # collective per parameter tensor)
     _perm2 = [(0, 1), (1, 0)]
     t_self = tau_of_selection(grid, onehot) if adaptive else None
-    if use_gather:
+    if use_gather or flat:
         keep_w = jax.lax.all_gather(keep, worker_axes)              # [W]
         if adaptive:
             width_w = jax.lax.all_gather(width, worker_axes)        # [W] sidecar
             tau_w = tau_of_width(grid, width_w)                     # [W]
-    elif use_permute:
+    if use_permute and not flat:
         peer_keep = jax.lax.ppermute(keep, worker_axes, _perm2)
         if adaptive:
             t_peer = jax.lax.ppermute(t_self, worker_axes, _perm2)
@@ -251,6 +263,16 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
         delta = (2.0 * t * Rb * codes.astype(jnp.float32) - Rb)
         delta = jnp.where(Rb > 0, delta, 0.0) * kb
         return jnp.sum(delta, axis=0)
+
+    def flat_dequant_sum(payload, R, orig):
+        # the whole-leaf wire: the payloads and radii gathered over workers
+        # at every W (at W=2 a two-term sum is the same bits in either
+        # order, so the swap's ppermute would buy nothing)
+        payload_w = jax.lax.all_gather(payload, worker_axes)
+        R_w = jax.lax.all_gather(R, worker_axes)
+        total = backend.dequant_acc(payload_w, R_w, keep_w, bits, orig.size)
+        with jax.named_scope(WIRE_CODEC):
+            return total.reshape(orig.shape)
 
     def permute_dequant_sum(q, R, orig, spec):
         # two-worker wire (pods): a single collective-permute payload
@@ -298,15 +320,22 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
     def stream_leaf(g, qh, R, spec):
         # the streamed hot path: this leaf's codes, payload and dequantized
         # delta are dead before the next leaf starts.  The send-side sweep
-        # is the backend's pass-2 leaf primitive: reference expressions on
-        # the reference backend (and the fused backend's CPU lowering), the
-        # fused codes+delta Pallas kernel off-CPU.
-        if adaptive:
+        # is the backend's pass-2 leaf primitive.  Whole-leaf wire: the
+        # payload kernel's rows off-CPU, the flat reference payload on CPU.
+        # Axis codec: the adaptive codes+delta Pallas kernel off-CPU, the
+        # reference expressions otherwise (every fixed-width sweep, and the
+        # adaptive one on CPU).
+        if flat:
+            payload, delta_local = backend.leaf_quantize_packed(g, qh, R,
+                                                                bits)
+            agg = flat_dequant_sum(payload, R, g)
+        elif adaptive:
             q, delta_local = backend.leaf_quantize_adaptive(
                 g, qh, R, grid, onehot, t_sel)
+            agg = leaf_fn(q, R, g, spec)
         else:
             q, delta_local = backend.leaf_quantize(g, qh, R, bits)
-        agg = leaf_fn(q, R, g, spec)
+            agg = leaf_fn(q, R, g, spec)
         q_new = qh.astype(jnp.float32) + delta_local
         return agg, q_new
 
@@ -370,7 +399,14 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             "norm-clipping on the packed wire would need a per-worker f32 "
             "scale sidecar (codes are integers); clip rides the float wire, "
             "validate/gate work on both (a reject is one mask bit)")
+    # --- partial-auto shard_map: manual over worker axes, auto over model ---
+    # Axes of size 1 go manual too: a Pallas kernel (the fused wire) lowers
+    # only where no axis is left for GSPMD to partition it over.
+    manual = set(worker_axes) | {a for a in mesh.axis_names
+                                 if mesh.shape[a] == 1}
     grad_pspecs = None
+    wire_layout = None
+    flat_wire = False
     if wire == "packed":
         assert strategy.quantized, "packed wire requires a quantized strategy"
         if strategy.adaptive:
@@ -382,6 +418,15 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
         from repro.models import init_params
         params_abs = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
         grad_pspecs = param_pspecs(cfg, params_abs, mesh.shape["model"])
+        # the whole-leaf wire where no axis is left to GSPMD (so every leaf
+        # is whole on its device) and the width is fixed; else the axis codec
+        flat_wire = not strategy.adaptive and manual == set(mesh.axis_names)
+        sizes = [leaf.size for leaf in jax.tree.leaves(params_abs)]
+        wire_layout = {"flat": (0, 0), "axis": (0, 0)}
+        wire_layout["flat" if flat_wire else "axis"] = (len(sizes), sum(sizes))
+        logging.getLogger(__name__).info(
+            "packed wire: %d leaves (%d parameters) flat, %d (%d) on the "
+            "axis codec", *wire_layout["flat"], *wire_layout["axis"])
 
     def sharded_step(params, opt_state, comm, batch, widx):
         qhat = _squeeze0(comm.qhat)
@@ -482,7 +527,8 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
                 skip = jnp.logical_not(wu.committed)
                 agg_delta, _ = _packed_aggregate(
                     grads, qhat, skip, strategy, wa, pspecs=grad_pspecs,
-                    width=width_m if strategy.adaptive else None)
+                    width=width_m if strategy.adaptive else None,
+                    flat=flat_wire)
 
             agg = jax.tree.map(lambda a, d: a.astype(jnp.float32) + d,
                                comm.server_agg, agg_delta)
@@ -522,12 +568,6 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             )
         return new_params, new_opt, new_comm, metrics
 
-    # --- partial-auto shard_map: manual over worker axes, auto over model ---
-    # Axes of size 1 go manual too: a Pallas kernel (the fused wire) lowers
-    # only where no axis is left for GSPMD to partition it over.
-    manual = set(worker_axes) | {a for a in mesh.axis_names
-                                 if mesh.shape[a] == 1}
-
     def step(state: TrainState, batch):
         comm = state.comm
         specs_comm = CommState(
@@ -560,8 +600,11 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             new_step = state.step + 1
         return TrainState(new_params, new_opt, new_comm, new_step), metrics
 
-    # introspection: the wire backend the sharded step runs
+    # introspection: the wire backend the sharded step runs, and on the
+    # packed wire how many leaves and parameters take each layout
+    # ({"flat": (leaves, params), "axis": (leaves, params)}; None on float)
     step.wire_backend = get_backend(strategy.wire_backend).name
+    step.wire_layout = wire_layout
     return step
 
 

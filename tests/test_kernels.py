@@ -11,7 +11,7 @@ import pytest
 
 from repro.core.quantize import quantize_codes, tau
 from repro.kernels import (absmax, dequant_acc, quantize_codes_adaptive,
-                           quantize_codes_fused, quantize_pack,
+                           quantize_pack,
                            quantize_pack_adaptive, quantize_pack_fused)
 from repro.kernels.quant_pack import BLOCK
 from repro.kernels.ref import (absmax_ref, dequant_acc_ref,
@@ -53,7 +53,8 @@ def test_quantize_pack_matches_ref(bits, n, dtype):
     pad = (-n) % BLOCK
     dpad = jnp.concatenate([diff, jnp.zeros((pad,))]) if pad else diff
     packed_ref, delta_ref = quantize_pack_ref(dpad, R, bits)
-    np.testing.assert_array_equal(np.asarray(packed), np.asarray(packed_ref))
+    np.testing.assert_array_equal(np.asarray(packed).reshape(-1),
+                                  np.asarray(packed_ref))
     np.testing.assert_allclose(np.asarray(delta), np.asarray(delta_ref[:n]),
                                atol=1e-5)
     # the LAQ error bound holds through the kernel
@@ -223,25 +224,28 @@ def test_quantize_pack_adaptive_zero_radius_block(sel):
 @pytest.mark.parametrize("sel", range(len(GRID)))
 @pytest.mark.parametrize("n", [3, 5000, BLOCK + 1])
 def test_quantize_codes_adaptive_matches_fixed(sel, n):
-    """The unpacked codes sweep (streamed sharded wire): adaptive == the
-    fixed-width sweep at the pinned width, codes exactly."""
+    """The unpacked codes sweep (the adaptive wire's axis codec): adaptive
+    == the fixed-width payload kernel at the pinned width, codes and delta
+    exactly, and both against the reference expressions."""
     bits = GRID[sel]
     key = jax.random.PRNGKey(n + 11 * sel)
     g = jax.random.normal(key, (n,)) * 3
     qh = jax.random.normal(jax.random.fold_in(key, 1), (n,))
     R = absmax(g, qh)
     codes_a, delta_a = quantize_codes_adaptive(g, qh, R, _onehot(sel), GRID)
-    codes_f, delta_f = quantize_codes_fused(g, qh, R, bits)
-    np.testing.assert_array_equal(np.asarray(codes_a), np.asarray(codes_f))
+    packed_f, delta_f = quantize_pack(g, qh, R, bits)
+    np.testing.assert_array_equal(np.asarray(codes_a),
+                                  _unpack(np.asarray(packed_f).reshape(-1),
+                                          bits, n))
     np.testing.assert_array_equal(np.asarray(delta_a), np.asarray(delta_f))
-    # and both against the reference expressions
+    # and against the reference expressions
     d = g.astype(jnp.float32) - qh.astype(jnp.float32)
-    np.testing.assert_array_equal(np.asarray(codes_f),
+    np.testing.assert_array_equal(np.asarray(codes_a),
                                   np.asarray(quantize_codes(d, R, bits)))
     t = tau(bits)
-    delta_ref = jnp.where(R > 0, 2.0 * t * R * codes_f.astype(jnp.float32) - R,
+    delta_ref = jnp.where(R > 0, 2.0 * t * R * codes_a.astype(jnp.float32) - R,
                           0.0)
-    np.testing.assert_allclose(np.asarray(delta_f), np.asarray(delta_ref),
+    np.testing.assert_allclose(np.asarray(delta_a), np.asarray(delta_ref),
                                atol=1e-5)
 
 
@@ -273,6 +277,53 @@ def test_dequant_acc_single_worker_zero_radius():
     acc = jnp.arange(n, dtype=jnp.float32)
     out = dequant_acc(packed, jnp.zeros((1,)), jnp.ones((1,)), 4, n, acc)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(acc))
+
+
+@pytest.mark.parametrize("bits", [2, 4, 8])
+@pytest.mark.parametrize("W", [1, 2, 4])
+@pytest.mark.parametrize("shape", [(7, 13), (3, 5, 33), (BLOCK + 3,)])
+def test_row_payload_decodes_like_the_axis_codec(bits, W, shape):
+    """The whole-leaf wire's kernel pair: each worker's row payload from
+    the send kernel, stacked over workers and decoded by ``dequant_acc``,
+    is the axis codec's masked sum (``launch/train.py``) bitwise, at odd
+    leaf shapes, with a skipped worker and a worker whose radius is 0."""
+    from repro.core.wire import (get_backend, pack_codes_along_axis,
+                                 unpack_codes_along_axis)
+    ref = get_backend("reference")
+    key = jax.random.PRNGKey(W * 100 + bits)
+    keep = jnp.array([1.0, 1.0, 0.0, 1.0][:W])
+    payloads, Rs, codes = [], [], []
+    for w in range(W):
+        k1, k2, key = jax.random.split(key, 3)
+        qh = jax.random.normal(k1, shape)
+        g = qh if w == 1 else qh + jax.random.normal(k2, shape) * 3
+        R = ref.leaf_absmax(g, qh)
+        pk, _ = quantize_pack(g, qh, R, bits)
+        q, _ = ref.leaf_quantize(g, qh, R, bits)
+        payloads.append(pk)
+        Rs.append(R)
+        codes.append(unpack_codes_along_axis(pack_codes_along_axis(q, bits),
+                                             bits, q))
+    Rw = jnp.stack(Rs)
+    n = int(np.prod(shape))
+    got = dequant_acc(jnp.stack(payloads), Rw, keep, bits, n).reshape(shape)
+    # the axis codec's decode and masked sum over the gathered workers,
+    # compiled as the step compiles it
+    @jax.jit
+    def axis_sum(codes, Rw, keep):
+        t = 1.0 / (2.0 ** bits - 1.0)
+        Rb = Rw.reshape((W,) + (1,) * len(shape))
+        kb = keep.reshape((W,) + (1,) * len(shape))
+        delta = 2.0 * t * Rb * codes.astype(jnp.float32) - Rb
+        return jnp.sum(jnp.where(Rb > 0, delta, 0.0) * kb, axis=0)
+
+    want = axis_sum(jnp.stack(codes), Rw, keep)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the reference decode takes the rows as they are too
+    ref_sum = jax.jit(dequant_acc_ref, static_argnums=(3, 4))(
+        jnp.stack(payloads), Rw, keep, bits, n)
+    np.testing.assert_array_equal(np.asarray(ref_sum).reshape(shape),
+                                  np.asarray(want))
 
 
 @hypothesis.given(scale=st.floats(1e-3, 1e3), bits=st.sampled_from([2, 4, 8]))

@@ -17,6 +17,7 @@ from repro.kernels import quant_pack as qp
 
 EMBED = 50288 * 768                      # Mamba2-130M's largest leaf
 N = -(-EMBED // qp.BLOCK) * qp.BLOCK     # padded to whole blocks, as ops.py does
+assert (EMBED, N // qp.BLOCK) == (38_621_184, 1_179)
 SPARSE_K = 1 << 23                       # ~5% of the model: an EF top-k payload
 GRID = (2, 4, 8)
 
@@ -58,10 +59,6 @@ def _kernel_cases():
     cases = {
         "absmax": (lambda g, q: qp.absmax_pallas(g, q, interpret=False),
                    leaf),
-        "quantize_codes": (
-            lambda g, q, R: qp.quantize_codes_pallas(g, q, R, 4,
-                                                     interpret=False),
-            leaf + [((1,), f32)]),
         "quantize_codes_adaptive": (
             lambda g, q, R, s: qp.quantize_codes_adaptive_pallas(
                 g, q, R, s, GRID, interpret=False),
@@ -78,6 +75,14 @@ def _kernel_cases():
             lambda p, R, k, a: qp.dequant_acc_pallas(
                 p, R, k, 4, N, a, interpret=False),
             [((4, N // 2), u8), ((4,), f32), ((4,), f32), ((N,), f32)]),
+        # the whole-leaf wire's pair, as the sharded step runs it on one
+        # chip: the send kernel's rows, stacked, into the decode kernel
+        "payload_rows_to_dequant_acc": (
+            lambda g, q, R, k: qp.dequant_acc_pallas(
+                qp.quantize_pack_payload_pallas(
+                    g, q, R, 4, interpret=False)[0][None],
+                R, k, 4, N, interpret=False),
+            leaf + [((1,), f32), ((1,), f32)]),
         "sparse_quant_pack": (
             lambda v, lo, hi: qp.sparse_quant_pack_pallas(
                 v, lo, hi, 1, interpret=False),
