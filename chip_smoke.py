@@ -11,7 +11,11 @@ With one chip, three phases, each of which must pass:
   (b) Mamba2-130M at its published config, whole, trained as LAQ (4-bit,
       packed wire, fused backend, AdamW) through examples/train_lm.py's
       path: the loss is finite and falls, the step runs the Pallas kernels;
-  (c) the same steps on the reference backend: params bitwise equal to (b).
+  (c) the same steps on the reference backend: params bitwise equal to (b);
+  (d) one LAQ round each of a small dense model and a small Zamba2 hybrid
+      (2,048 tokens over two query chunks, the second scanning four kv
+      chunks): every attention leaf of the dense layers and of the shared
+      blocks, and the embedding, gets a finite, non-zero aggregate.
 
 With ``--chips 4``, only this: Mamba2-130M on a (4, 1) mesh, four LAQ
 workers, packed wire against float wire, params exactly equal.
@@ -223,6 +227,43 @@ def train_phase(backend, wire, steps, batch, seq, lr, seed, n_devices):
     return run, params, hlo
 
 
+def attention_phase(seed):
+    """(d): attention's gradients survive the chip, in a dense model's
+    layers and in Zamba2's shared block."""
+    import dataclasses
+
+    from repro.configs import get_config, smoke_config
+    from repro.core.strategy import StrategyConfig
+    from repro.launch.mesh import device_mesh
+    from repro.launch.train import init_train_state, make_train_step
+    from repro.optim import adamw
+
+    for arch, attn in (("stablelm-1.6b", "blocks"), ("zamba2-7b", "shared")):
+        cfg = dataclasses.replace(smoke_config(get_config(arch)),
+                                  q_chunk=1024, kv_chunk=512)
+        mesh = device_mesh(n_devices=1)
+        strategy = StrategyConfig(kind="laq", bits=4, per_leaf_radius=True,
+                                  wire_backend="fused")
+        opt = adamw()
+        step = jax.jit(make_train_step(cfg, mesh, strategy, opt, lr=1e-3,
+                                       worker_axes=("data",), wire="packed"))
+        state = init_train_state(jax.random.PRNGKey(seed), cfg, mesh,
+                                 strategy, opt, ("data",))
+        s = jax.random.randint(jax.random.PRNGKey(seed + 1), (1, 2049), 0,
+                               cfg.vocab)
+        state, m = step(state, {"tokens": s[:, :-1], "targets": s[:, 1:]})
+        log(f"  {arch}: loss {float(m.loss)!r} uploads {int(m.uploads)}")
+        mu = state.opt_state.mu
+        leaves = dict(jax.tree_util.tree_leaves_with_path(mu[attn]["attn"]))
+        leaves["embed"] = mu["embed"]
+        for path, leaf in leaves.items():
+            name = path if isinstance(path, str) else jax.tree_util.keystr(path)
+            n = float(jnp.linalg.norm(leaf.astype(jnp.float32)))
+            log(f"  {arch} aggregate norm {name}: {n!r}")
+            check(np.isfinite(n) and n > 0,
+                  f"{arch} {name}'s aggregate is finite and non-zero")
+
+
 def peak_bytes():
     stats = jax.devices()[0].memory_stats() or {}
     return stats.get("peak_bytes_in_use")
@@ -308,6 +349,12 @@ def main():
         report_diff(fused, reference)
         same = params_equal(fused, reference)
         check(same, "fused and reference params bitwise equal")
+        del run
+        gc.collect()
+
+        log("[d] small dense model and Zamba2 hybrid, one LAQ round each, "
+            "attention gradients")
+        attention_phase(args.seed)
     else:
         log("[4 chips] Mamba2-130M, 4 LAQ workers, packed wire vs float wire")
         batch = 4 * args.batch

@@ -14,7 +14,7 @@ import importlib
 from repro.models.config import INPUT_SHAPES, InputShape, ModelConfig
 
 _MODULES = {
-    "zamba2-2.7b": "zamba2_2p7b",
+    "zamba2-7b": "zamba2_7b",
     "qwen3-8b": "qwen3_8b",
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "yi-6b": "yi_6b",
@@ -62,8 +62,10 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         kw.update(n_experts=4, top_k=min(2, cfg.top_k), moe_d_ff=64)
     if cfg.ssm_state:
         kw.update(ssm_state=16, ssm_head_dim=32)
-    if cfg.attn_every:
-        kw.update(attn_every=2)
+    if cfg.hybrid_layers:
+        # both layers hybrid, so each of (at most) two shared blocks runs
+        kw.update(hybrid_layers=(0, 1), num_mem_blocks=min(cfg.num_mem_blocks, 2),
+                  adapter_rank=8)
     if cfg.sliding_window:
         kw.update(sliding_window=64)
     return dataclasses.replace(cfg, **kw)

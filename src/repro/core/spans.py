@@ -18,6 +18,9 @@ Spans and the code each covers:
 * ``laq.lazy_rule`` — ``worker_update`` (criterion moments, skip decision,
   clocks, bits), the history of the parameter motion, and the round's
   counters and metrics.
+* ``laq.shared_block`` — inside ``laq.fwd_bwd``, a hybrid model's shared
+  attention block with its invocation's adapter and linear
+  (``models/stack.py shared_block``), forward, recomputation and backward.
 * ``laq.wire.quantize`` — the wire backends' radius and quantize
   primitives (``core/wire.py``), the Pallas kernels and their operand
   casts and pads included.
@@ -41,13 +44,15 @@ import functools
 import jax
 
 FWD_BWD = "laq.fwd_bwd"
+SHARED_BLOCK = "laq.shared_block"
 LAZY_RULE = "laq.lazy_rule"
 WIRE_QUANTIZE = "laq.wire.quantize"
 WIRE_CODEC = "laq.wire.codec"
 AGGREGATE = "laq.aggregate"
 OPTIMIZER = "laq.optimizer"
 
-SPANS = (FWD_BWD, LAZY_RULE, WIRE_QUANTIZE, WIRE_CODEC, AGGREGATE, OPTIMIZER)
+SPANS = (FWD_BWD, SHARED_BLOCK, LAZY_RULE, WIRE_QUANTIZE, WIRE_CODEC,
+         AGGREGATE, OPTIMIZER)
 
 
 def scoped(name: str):

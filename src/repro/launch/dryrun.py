@@ -78,25 +78,28 @@ def roofline_probe(cfg, shape, mesh, strategy, opt, wire, hierarchical,
     XLA cost_analysis counts a while/scan body once regardless of trip count,
     so the scanned full-depth compile under-reports per-layer work.  We lower
     unrolled variants at L = 0 (isolates the fixed embed/head/LAQ part
-    exactly, nearly-free compile) and L = unit (one whole period; unit =
-    attn_every for hybrids) and extrapolate: total = fixed + n_units*per_unit.
-    Exact for homogeneous stacks since per-layer cost is index-independent.
+    exactly, nearly-free compile), at L = 1 and, for hybrids, at L = 1 with
+    that layer hybrid, and extrapolate: total = fixed + n_layers*per_layer
+    + n_hybrid*per_shared_block.  Exact since per-layer cost is
+    index-independent.
     """
-    unit = cfg.attn_every if cfg.arch_type == "hybrid" else 1
-    costs = []
-    for L in (0, unit):
-        cfg_L = dataclasses.replace(cfg, n_layers=L, scan_layers=False)
+    def probe(**kw):
+        cfg_L = dataclasses.replace(cfg, scan_layers=False, **kw)
         lowered = _build_lowered(cfg_L, shape, mesh, strategy, opt, wire,
                                  hierarchical, multi_pod, microbatch)
-        costs.append(_probe_costs(lowered.compile()))
-    (f0, b0, c0), (f1, b1, c1) = costs
-    n_units = cfg.n_layers // unit
-    def extrap(fixed, v1):
-        per = max(v1 - fixed, 0.0)
-        return fixed + n_units * per
-    flops = extrap(f0, f1)
-    hbm = extrap(b0, b1)
-    coll = {k: extrap(c0[k], c1[k]) for k in c0}
+        return _probe_costs(lowered.compile())
+
+    n_hybrid = len(cfg.hybrid_layers)
+    fixed = probe(n_layers=0, hybrid_layers=())
+    one = probe(n_layers=1, hybrid_layers=())
+    hyb = probe(n_layers=1, hybrid_layers=(0,)) if n_hybrid else one
+
+    def extrap(f, v1, vh):
+        return (f + cfg.n_layers * max(v1 - f, 0.0)
+                + n_hybrid * max(vh - v1, 0.0))
+    flops = extrap(fixed[0], one[0], hyb[0])
+    hbm = extrap(fixed[1], one[1], hyb[1])
+    coll = {k: extrap(fixed[2][k], one[2][k], hyb[2][k]) for k in fixed[2]}
     return flops, hbm, coll
 
 
@@ -188,7 +191,7 @@ def main():
     args = ap.parse_args()
 
     fast_order = ["mamba2-130m", "stablelm-1.6b", "musicgen-medium",
-                  "qwen3-moe-30b-a3b", "yi-6b", "zamba2-2.7b", "qwen3-8b",
+                  "qwen3-moe-30b-a3b", "yi-6b", "zamba2-7b", "qwen3-8b",
                   "yi-9b", "phi3.5-moe-42b-a6.6b", "chameleon-34b"]
     archs = fast_order if args.arch == "all" else args.arch.split(",")
     shapes = list(INPUT_SHAPES) if args.shape == "all" else args.shape.split(",")

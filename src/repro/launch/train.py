@@ -120,7 +120,7 @@ from repro.core.wire import (get_backend, pack_codes_along_axis,
                              unpack_codes_along_axis)
 from repro.core.criterion import push_history
 from repro.models import lm_loss, param_pspecs
-from repro.models.config import ModelConfig
+from repro.models.config import ModelConfig, shared_block_params
 from repro.optim import Optimizer
 
 from .mesh import auto_axes, n_workers_of
@@ -428,6 +428,20 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             "packed wire: %d leaves (%d parameters) flat, %d (%d) on the "
             "axis codec", *wire_layout["flat"], *wire_layout["axis"])
 
+    shared_block = None
+    if cfg.arch_type == "hybrid":
+        shared_block = dict(
+            heads=cfg.n_heads, kv_heads=cfg.n_kv_heads, mlp_columns=cfg.d_ff,
+            ssm_heads=cfg.ssm_heads, ssm_groups=cfg.ssm_n_groups,
+            invocations=len(cfg.hybrid_layers), blocks=cfg.num_mem_blocks,
+            params=shared_block_params(cfg))
+        logging.getLogger(__name__).info(
+            "hybrid: %(heads)d attention heads (%(kv_heads)d kv), "
+            "%(mlp_columns)d MLP columns, %(ssm_heads)d Mamba heads in "
+            "%(ssm_groups)d groups held; %(invocations)d shared-block "
+            "invocations per forward over %(blocks)d blocks of %(params)d "
+            "parameters", shared_block)
+
     def sharded_step(params, opt_state, comm, batch, widx):
         qhat = _squeeze0(comm.qhat)
         eps_hat_sq = jnp.squeeze(comm.eps_hat_sq, 0)
@@ -605,6 +619,8 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
     # ({"flat": (leaves, params), "axis": (leaves, params)}; None on float)
     step.wire_backend = get_backend(strategy.wire_backend).name
     step.wire_layout = wire_layout
+    # a hybrid model's shares and shared-block count (None otherwise)
+    step.shared_block = shared_block
     return step
 
 

@@ -27,15 +27,18 @@ from .config import ModelConfig
 from .layers import apply_rope, normal_init, rms_norm
 
 
-def init_attention(key, cfg: ModelConfig, dtype):
+def init_attention(key, cfg: ModelConfig, dtype, d_in: int = 0):
+    """q/k/v read ``d_in`` features (``d_model`` by default); ``wo`` writes
+    ``d_model``."""
     D, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    d_in = d_in or D
     ks = jax.random.split(key, 4)
-    s_in = D ** -0.5
+    s_in = d_in ** -0.5
     s_out = (H * hd) ** -0.5
     p = {
-        "wq": normal_init(ks[0], (D, H * hd), s_in, dtype),
-        "wk": normal_init(ks[1], (D, KV * hd), s_in, dtype),
-        "wv": normal_init(ks[2], (D, KV * hd), s_in, dtype),
+        "wq": normal_init(ks[0], (d_in, H * hd), s_in, dtype),
+        "wk": normal_init(ks[1], (d_in, KV * hd), s_in, dtype),
+        "wv": normal_init(ks[2], (d_in, KV * hd), s_in, dtype),
         "wo": normal_init(ks[3], (H * hd, D), s_out, dtype),
     }
     if cfg.qk_norm:
@@ -58,12 +61,14 @@ def _project_qkv(p, x, positions, cfg: ModelConfig):
     return q, k, v
 
 
-def chunked_causal_attention(q, k, v, q_positions, kv_positions, cfg: ModelConfig):
-    """Online-softmax causal attention. q:[B,S,H,hd] k,v:[B,S,KV,hd]."""
+def chunked_causal_attention(q, k, v, q_positions, kv_positions,
+                             cfg: ModelConfig, scale: float = 0.0):
+    """Online-softmax causal attention. q:[B,S,H,hd] k,v:[B,S,KV,hd].
+    Scores are scaled by ``scale`` (``1/sqrt(hd)`` by default)."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
     Cq = min(cfg.q_chunk, S)
     if S % Cq:
         Cq = S                      # irregular lengths: one q chunk
@@ -90,7 +95,12 @@ def chunked_causal_attention(q, k, v, q_positions, kv_positions, cfg: ModelConfi
             if cfg.sliding_window:
                 mask &= (qp[:, None] - kpj[None, :]) < cfg.sliding_window
             s = jnp.where(mask[None, None, None], s, -1e30)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            # the running max only keeps exp() in range and the output does
+            # not depend on it, so it is not differentiated.  Its derivative
+            # would test which scores equal it; on the TPU XLA can take the
+            # max from the scores before their bf16 rounding, so no score
+            # equals it and the test's count of 0 divides into a NaN.
+            m_new = jax.lax.stop_gradient(jnp.maximum(m, jnp.max(s, axis=-1)))
             pexp = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
             l_new = l * corr + jnp.sum(pexp, axis=-1)
@@ -108,8 +118,9 @@ def chunked_causal_attention(q, k, v, q_positions, kv_positions, cfg: ModelConfi
     return jnp.concatenate(out_chunks, axis=1)
 
 
-def attention_forward(p, x, positions, cfg: ModelConfig, *, return_kv: bool = False):
-    """Train/prefill path. x:[B,S,D]; positions:[S]."""
+def attention_forward(p, x, positions, cfg: ModelConfig, *, return_kv: bool = False,
+                      scale: float = 0.0):
+    """Train/prefill path. x:[B,S,d_in]; positions:[S]."""
     from .layers import maybe_constrain
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, positions, cfg)
@@ -119,7 +130,7 @@ def attention_forward(p, x, positions, cfg: ModelConfig, *, return_kv: bool = Fa
         q = maybe_constrain(q, "model", None, None, None)
         k = maybe_constrain(k, "model", None, None, None)
         v = maybe_constrain(v, "model", None, None, None)
-    o = chunked_causal_attention(q, k, v, positions, positions, cfg)
+    o = chunked_causal_attention(q, k, v, positions, positions, cfg, scale)
     if cfg.attn_batch_shard:
         o = maybe_constrain(o, None, None, None, None)
     out = o.reshape(B, S, cfg.n_heads * cfg.hd) @ p["wo"]
@@ -137,8 +148,9 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, n_blocks: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def decode_attention(p, x, cache_k, cache_v, pos, cfg: ModelConfig):
-    """One-token decode. x:[B,1,D]; cache_[kv]:[B,Sc,KV,hd]; pos: scalar.
+def decode_attention(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
+                     scale: float = 0.0):
+    """One-token decode. x:[B,1,d_in]; cache_[kv]:[B,Sc,KV,hd]; pos: scalar.
 
     Returns (out [B,1,D], new_k, new_v).  The cache sequence dim is expected
     to be sharded over the model axis; the softmax/PV reductions over it
@@ -156,7 +168,7 @@ def decode_attention(p, x, cache_k, cache_v, pos, cfg: ModelConfig):
 
     qg = q.reshape(B, 1, KV, G, hd)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, new_k.astype(q.dtype)).astype(jnp.float32)
-    s = s / math.sqrt(hd)
+    s = s * scale if scale else s / math.sqrt(hd)
     valid = jnp.arange(Sc) < jnp.minimum(pos + 1, Sc)   # full + ring buffer
     s = jnp.where(valid[None, None, None, None, :], s, -1e30)
     w = jax.nn.softmax(s, axis=-1)

@@ -50,8 +50,16 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_n_groups: int = 1
     ssm_chunk: int = 128            # SSD intra-chunk quadratic block size
-    # hybrid: run the shared attention block after every `attn_every` layers
-    attn_every: int = 0
+    # Mamba heads held (a tensor-parallel share); 0 => ssm_expand * d_model
+    # / ssm_head_dim, the whole mixer
+    ssm_n_heads: int = 0
+    # hybrid (Zamba2): the layers whose Mamba input first receives the
+    # shared attention block's output; ``num_mem_blocks`` shared blocks
+    # alternate over them, each invocation with its own adapter of rank
+    # ``adapter_rank`` and its own output ``linear``
+    hybrid_layers: tuple = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
     # long-context: ring-buffer KV cache window for decode (0 = full cache)
     sliding_window: int = 0
     # numerics
@@ -75,12 +83,18 @@ class ModelConfig:
     # per the carve-out); the backbone consumes ids like any LM.
     frontend: Optional[str] = None  # "vq_image" | "encodec" | None
 
+    def __post_init__(self):
+        # a JSON configuration gives a list; the config stays hashable
+        object.__setattr__(self, "hybrid_layers", tuple(self.hybrid_layers))
+
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
 
     @property
     def d_inner(self) -> int:
+        if self.ssm_n_heads:
+            return self.ssm_n_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
 
     @property
@@ -93,7 +107,7 @@ class ModelConfig:
 
     @property
     def has_attention(self) -> bool:
-        return self.n_heads > 0 or self.attn_every > 0
+        return self.n_heads > 0
 
     @property
     def is_recurrent(self) -> bool:
@@ -144,7 +158,9 @@ def n_params(cfg: ModelConfig) -> int:
 
     if cfg.arch_type == "hybrid":
         total += cfg.n_layers * (per_mamba + D)      # mamba blocks + ln
-        total += per_attn + per_mlp + 2 * D          # one shared attn block
+        total += cfg.num_mem_blocks * shared_block_params(cfg)
+        r, F = cfg.adapter_rank, cfg.d_ff
+        total += len(cfg.hybrid_layers) * (D * r + 2 * r * F + D * D)
     elif cfg.arch_type == "ssm":
         total += cfg.n_layers * (per_mamba + D)
     elif cfg.arch_type == "moe":
@@ -152,6 +168,14 @@ def n_params(cfg: ModelConfig) -> int:
     else:                                            # dense / vlm / audio
         total += cfg.n_layers * (per_attn + per_mlp + 2 * D)
     return total
+
+
+def shared_block_params(cfg: ModelConfig) -> int:
+    """One Zamba2 shared block: its two norms, attention reading the
+    ``2 * d_model`` concatenation, and the GELU-gated MLP."""
+    D, hd = cfg.d_model, cfg.hd
+    attn = 2 * D * (cfg.n_heads + 2 * cfg.n_kv_heads) * hd + cfg.n_heads * hd * D
+    return 2 * D + attn + D + 3 * D * cfg.d_ff
 
 
 def n_active_params(cfg: ModelConfig) -> int:

@@ -1,4 +1,5 @@
-"""Shared layers: norms, rotary embeddings, SwiGLU MLP, initializers."""
+"""Shared layers: norms, rotary embeddings, SwiGLU and GELU-gated MLPs,
+initializers."""
 from __future__ import annotations
 
 import jax
@@ -49,6 +50,17 @@ def apply_rope(x, positions, theta: float):
 def swiglu(x, w_gate, w_up, w_down):
     h = jax.nn.silu(x @ w_gate) * (x @ w_up)
     return h @ w_down
+
+
+def gelu_adapted_mlp(x, w_gate, w_up, w_down, adapter_in, adapter_gate,
+                     adapter_up):
+    """Zamba2's shared MLP: ``gelu(g) * u`` (erf GELU), where ``g`` and ``u``
+    each add a low-rank adapter's share, ``(x @ adapter_in) @ adapter_*``, to
+    the shared projection."""
+    a = x @ adapter_in
+    g = x @ w_gate + a @ adapter_gate
+    u = x @ w_up + a @ adapter_up
+    return (jax.nn.gelu(g, approximate=False) * u) @ w_down
 
 
 def init_mlp(key, d_model: int, d_ff: int, dtype):

@@ -58,7 +58,7 @@ def _causal_conv(u, w, b):
 
 
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int):
-    """SSD scan. x:[B,S,H,P] dt:[B,S,H] A:[H] Bm,Cm:[B,S,N] (G=1).
+    """SSD scan of one group. x:[B,S,H,P] dt:[B,S,H] A:[H] Bm,Cm:[B,S,N].
 
     Returns y:[B,S,H,P] and the final state [B,H,P,N].
     """
@@ -118,6 +118,14 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int):
     return y.astype(x.dtype), Rfinal.transpose(0, 1, 3, 2)
 
 
+def _gated_norm(y, z, w, cfg: ModelConfig):
+    """RMS norm of ``y * silu(z)`` over each group's ``d_inner / G``
+    channels."""
+    G = cfg.ssm_n_groups
+    h = (y * jax.nn.silu(z)).reshape(y.shape[:-1] + (G, -1))
+    return rms_norm(h, w.reshape(G, -1), cfg.norm_eps).reshape(y.shape)
+
+
 def mamba2_forward(p, x, cfg: ModelConfig, chunk: int = 0):
     """Train/prefill path. x:[B,S,D] -> ([B,S,D], final_state, conv_tail)."""
     chunk = chunk or cfg.ssm_chunk
@@ -130,10 +138,17 @@ def mamba2_forward(p, x, cfg: ModelConfig, chunk: int = 0):
     xin, Bm, Cm = jax.nn.silu(xin), jax.nn.silu(Bm), jax.nn.silu(Cm)
     dt = jax.nn.softplus((x @ p["w_dt"]).astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
-    y, state = ssd_chunked(xin.reshape(B, S, H, P), dt, A, Bm, Cm, chunk)
-    y = y + xin.reshape(B, S, H, P) * p["Dp"][:, None].astype(x.dtype)
+    # each group's heads read that group's B and C
+    Hg, xh = H // cfg.ssm_n_groups, xin.reshape(B, S, H, P)
+    parts = [ssd_chunked(xh[:, :, g * Hg:(g + 1) * Hg],
+                         dt[..., g * Hg:(g + 1) * Hg], A[g * Hg:(g + 1) * Hg],
+                         Bm[..., g * N:(g + 1) * N], Cm[..., g * N:(g + 1) * N],
+                         chunk) for g in range(cfg.ssm_n_groups)]
+    y = jnp.concatenate([y_ for y_, _ in parts], axis=2)
+    state = jnp.concatenate([s_ for _, s_ in parts], axis=1)
+    y = y + xh * p["Dp"][:, None].astype(x.dtype)
     y = y.reshape(B, S, cfg.d_inner)
-    y = rms_norm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
+    y = _gated_norm(y, z, p["gate_norm"], cfg)
     out = y @ p["out_proj"]
     # conv tail: last K-1 *pre-conv* projected inputs, for decode continuation
     K = cfg.ssm_conv
@@ -178,12 +193,16 @@ def mamba2_decode(p, x, cache, cfg: ModelConfig):
     A = -jnp.exp(p["A_log"])
     dA = jnp.exp(dt * A)                                        # [B,H]
     xh = xin.reshape(B, H, P).astype(jnp.float32)
+    # each head reads its group's B and C
+    G = cfg.ssm_n_groups
+    Bh = jnp.repeat(Bm.reshape(B, G, N), H // G, axis=1).astype(jnp.float32)
+    Ch = jnp.repeat(Cm.reshape(B, G, N), H // G, axis=1).astype(jnp.float32)
     s = cache["ssm"] * dA[..., None, None] + \
-        jnp.einsum("bh,bhp,bn->bhpn", dt, xh, Bm.astype(jnp.float32))
-    y = jnp.einsum("bhpn,bn->bhp", s, Cm.astype(jnp.float32))   # [B,H,P]
+        jnp.einsum("bh,bhp,bhn->bhpn", dt, xh, Bh)
+    y = jnp.einsum("bhpn,bhn->bhp", s, Ch)                      # [B,H,P]
     y = y + xh * p["Dp"][:, None]
     y = y.reshape(B, cfg.d_inner).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
+    y = _gated_norm(y, z, p["gate_norm"], cfg)
     out = (y @ p["out_proj"])[:, None]
     new_cache = {"ssm": s, "conv_x": tail_x, "conv_B": tail_B, "conv_C": tail_C}
     return out, new_cache

@@ -30,7 +30,8 @@ def param_pspecs(cfg: ModelConfig, params, model_size: int, model_axis="model"):
     def rule(path, leaf):
         names = [getattr(k, "key", str(k)) for k in path]
         name = names[-1]
-        stacked = "blocks" in names          # leading layer dim
+        # a leading layer, shared-block or invocation dim
+        stacked = names[0] in ("blocks", "shared", "hybrid")
         def wrap(*spec):
             return P(*(((None,) + spec) if stacked else spec))
 
@@ -56,6 +57,9 @@ def param_pspecs(cfg: ModelConfig, params, model_size: int, model_axis="model"):
             if "moe" in names:
                 return wrap(m if _div(cfg.n_experts, ms) else None, None, None)
             return wrap(m if _div(cfg.d_ff, ms) else None, None)
+        # hybrid invocation: the adapter's second matrix splits like gate/up
+        if name in ("adapter_gate", "adapter_up"):
+            return wrap(None, m if _div(cfg.d_ff, ms) else None)
         if name == "router":
             return wrap(None, None)
         # mamba2

@@ -5,20 +5,35 @@ forward is a single ``lax.scan`` over it (MaxText-style), so the HLO contains
 one layer body regardless of depth — essential to keep 36-54-layer models
 compilable on the 512-device dry-run meshes.
 
-Hybrid (Zamba2-style) stacks scan over Mamba2 blocks and apply one *shared*
-attention+MLP block (single weight set) after every ``cfg.attn_every``-th
-layer via ``lax.cond``; its per-application KV caches are carried as a
-stacked ``[n_shared, ...]`` array.
+Hybrid stacks are Zamba2's (arXiv:2411.15242; the layer equations of
+``transformers``' ``Zamba2ForCausalLM``).  Every layer is a Mamba2 layer
+``x + mamba(norm(x + t))``; ``t`` is 0, except at the layers
+``cfg.hybrid_layers`` lists, where it is the output of a *shared* block fed
+with the embedding output ``e``:
+
+    t = linear_j(mlp_b(norm(attn_b(norm(cat(x, e))))))
+
+``attn_b`` reads the ``2 * d_model`` concatenation and scores at
+``(hd / 2) ** -0.5``; ``mlp_b`` is ``gelu(g) * u`` with invocation ``j``'s
+low-rank adapter added to ``g`` and ``u``; the block has no residual of its
+own.  The ``cfg.num_mem_blocks`` shared blocks ``b = j % num_mem_blocks``
+alternate over the invocations ``j``; each invocation has its own adapter
+and ``linear``.  The runs of Mamba layers between hybrid layers are scanned;
+each hybrid layer is its own call, so its invocation's weights are static
+slices.  Its per-invocation KV caches are a stacked ``[n_hybrid, ...]``
+array.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import SHARED_BLOCK
+
 from .attention import (attention_forward, decode_attention, init_attention,
                         init_kv_cache)
 from .config import ModelConfig
-from .layers import init_mlp, normal_init, rms_norm, swiglu
+from .layers import gelu_adapted_mlp, init_mlp, normal_init, rms_norm, swiglu
 from .mamba2 import (init_mamba2, init_mamba_cache, mamba2_decode,
                      mamba2_forward)
 from .moe import init_moe, moe_forward, moe_forward_dense
@@ -45,6 +60,25 @@ def init_mamba_block(key, cfg: ModelConfig, dtype):
             "mamba": init_mamba2(key, cfg, dtype)}
 
 
+def init_shared_block(key, cfg: ModelConfig, dtype):
+    D = cfg.d_model
+    k1, k2 = jax.random.split(key)
+    return {"ln_in": jnp.zeros((2 * D,), jnp.float32),
+            "attn": init_attention(k1, cfg, dtype, d_in=2 * D),
+            "ln_mlp": jnp.zeros((D,), jnp.float32),
+            "mlp": init_mlp(k2, D, cfg.d_ff, dtype)}
+
+
+def init_invocation(key, cfg: ModelConfig, dtype):
+    """One hybrid layer's own weights: the MLP adapter and the linear."""
+    D, F, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    ks = jax.random.split(key, 4)
+    return {"adapter_in": normal_init(ks[0], (D, r), D ** -0.5, dtype),
+            "adapter_gate": normal_init(ks[1], (r, F), r ** -0.5, dtype),
+            "adapter_up": normal_init(ks[2], (r, F), r ** -0.5, dtype),
+            "linear": normal_init(ks[3], (D, D), D ** -0.5, dtype)}
+
+
 def init_params(key, cfg: ModelConfig):
     dtype = cfg.param_dtype
     V, D = cfg.padded_vocab(), cfg.d_model
@@ -61,14 +95,14 @@ def init_params(key, cfg: ModelConfig):
         params["blocks"] = jax.vmap(lambda k: init_mamba_block(k, cfg, dtype))(keys)
     elif cfg.arch_type == "hybrid":
         params["blocks"] = jax.vmap(lambda k: init_mamba_block(k, cfg, dtype))(keys)
-        params["shared_attn"] = init_attn_block(k_shared, cfg, dtype)
+        k_mem, k_inv = jax.random.split(k_shared)
+        params["shared"] = jax.vmap(lambda k: init_shared_block(k, cfg, dtype))(
+            jax.random.split(k_mem, cfg.num_mem_blocks))
+        params["hybrid"] = jax.vmap(lambda k: init_invocation(k, cfg, dtype))(
+            jax.random.split(k_inv, len(cfg.hybrid_layers)))
     else:
         raise ValueError(cfg.arch_type)
     return params
-
-
-def n_shared_applications(cfg: ModelConfig) -> int:
-    return cfg.n_layers // cfg.attn_every if cfg.attn_every else 0
 
 
 # ---------------------------------------------------------------------------
@@ -91,9 +125,81 @@ def attn_block_fwd(bp, x, positions, cfg: ModelConfig, *, return_kv=False):
     return x + m, aux, kv
 
 
-def mamba_block_fwd(bp, x, cfg: ModelConfig, *, return_state=False):
-    h, state, tail = mamba2_forward(bp["mamba"], rms_norm(x, bp["ln"], cfg.norm_eps), cfg)
+def mamba_block_fwd(bp, x, cfg: ModelConfig, *, return_state=False, t=None):
+    """``x + mamba(norm(x))``, or ``x + mamba(norm(x + t))`` at a hybrid
+    layer."""
+    h_in = x if t is None else x + t
+    h, state, tail = mamba2_forward(bp["mamba"], rms_norm(h_in, bp["ln"], cfg.norm_eps), cfg)
     return x + h, (state, tail) if return_state else None
+
+
+def shared_block(sp, hp, x, e, attend, cfg: ModelConfig):
+    """The shared block's output ``t`` at one hybrid layer, and what
+    ``attend(attn_params, h, scale)`` returns beside the attention output
+    (its KV).  ``sp``: the shared block's weights; ``hp``: the invocation's
+    adapter and linear.  Forward, prefill and decode differ only in
+    ``attend``."""
+    with jax.named_scope(SHARED_BLOCK):
+        h = rms_norm(jnp.concatenate([x, e], axis=-1), sp["ln_in"], cfg.norm_eps)
+        a, kv = attend(sp["attn"], h, (cfg.hd / 2) ** -0.5)
+        h = rms_norm(a, sp["ln_mlp"], cfg.norm_eps)
+        m = gelu_adapted_mlp(h, **sp["mlp"], adapter_in=hp["adapter_in"],
+                             adapter_gate=hp["adapter_gate"],
+                             adapter_up=hp["adapter_up"])
+        return (m @ hp["linear"]).astype(x.dtype), kv
+
+
+def _hybrid_stack(params, x, cfg: ModelConfig, layer, mcache=None, kv=None):
+    """The hybrid stack in layer order.  ``layer(bp, x, mc, inv)`` runs one
+    layer: ``inv`` is ``None`` for a Mamba layer, else ``(sp, hp, kvj)``,
+    the shared block, adapter and linear of the hybrid layer's invocation
+    ``j`` and its KV cache (``None`` without one); it returns ``(x, mc_new,
+    kv_new)``.  ``mcache`` (``kv``) is a per-layer (per-invocation) tree
+    with a leading axis, or ``None``.  Runs of Mamba layers are one scan
+    each (unrolled where ``scan_layers`` is off).  Returns ``x``, the new
+    per-layer tree (or ``None``) and the invocations' KV in order."""
+    blocks = params["blocks"]
+    out_m, out_kv = [], []
+
+    def part(tree, lo, hi):
+        return None if tree is None else jax.tree.map(lambda a: a[lo:hi], tree)
+
+    def run(x, lo, hi):
+        if hi <= lo:
+            return x
+        bps, mcs = part(blocks, lo, hi), part(mcache, lo, hi)
+        if cfg.scan_layers:
+            def body(x, xs):
+                x, mc, _ = layer(xs[0], x, xs[1], None)
+                return x, mc
+            x, mcs_new = jax.lax.scan(body, x, (bps, mcs))
+        else:
+            got = []
+            for i in range(hi - lo):
+                x, mc, _ = layer(_layer_slice(bps, i), x,
+                                 None if mcs is None else _layer_slice(mcs, i), None)
+                got.append(mc)
+            mcs_new = (None if mcache is None
+                       else jax.tree.map(lambda *a: jnp.stack(a), *got))
+        out_m.append(mcs_new)
+        return x
+
+    lo = 0
+    for j, h in enumerate(cfg.hybrid_layers):
+        x = run(x, lo, h)
+        inv = (_layer_slice(params["shared"], j % cfg.num_mem_blocks),
+               _layer_slice(params["hybrid"], j),
+               None if kv is None else _layer_slice(kv, j))
+        x, mc, kv_j = layer(_layer_slice(blocks, h), x,
+                            None if mcache is None else _layer_slice(mcache, h),
+                            inv)
+        out_m.append(None if mc is None else jax.tree.map(lambda a: a[None], mc))
+        out_kv.append(kv_j)
+        lo = h + 1
+    x = run(x, lo, cfg.n_layers)
+    new_m = (None if mcache is None else
+             jax.tree.map(lambda *a: jnp.concatenate(a), *out_m))
+    return x, new_m, out_kv
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +217,22 @@ def forward(params, tokens, cfg: ModelConfig):
 
     # the scan carry keeps the compute dtype: params held in f32 (the SVRG
     # anchor) would otherwise promote a layer's output past its input
-    if not cfg.scan_layers:
+    if cfg.arch_type == "hybrid":
+        e = x
+
+        def attend(ap, h, scale):
+            return attention_forward(ap, h, positions, cfg, scale=scale), None
+
+        @maybe_remat
+        def layer(bp, x, mc, inv):
+            t = None
+            if inv is not None:
+                t, _ = shared_block(inv[0], inv[1], x, e, attend, cfg)
+            return mamba_block_fwd(bp, x, cfg, t=t)[0].astype(x.dtype), None, None
+
+        x, _, _ = _hybrid_stack(params, x, cfg, layer)
+        aux = jnp.zeros((), jnp.float32)
+    elif not cfg.scan_layers:
         x, aux = _forward_unrolled(params, x, positions, cfg, maybe_remat)
     elif cfg.arch_type in ("dense", "moe", "vlm", "audio"):
         @maybe_remat
@@ -135,26 +256,6 @@ def forward(params, tokens, cfg: ModelConfig):
             return layer(x, bp), None
         x, _ = jax.lax.scan(body, x, params["blocks"])
         aux = jnp.zeros((), jnp.float32)
-    elif cfg.arch_type == "hybrid":
-        shared = params["shared_attn"]
-
-        @maybe_remat
-        def layer(x, bp, idx):
-            x = mamba_block_fwd(bp, x, cfg)[0].astype(x.dtype)
-            def with_attn(x):
-                y, _, _ = attn_block_fwd(shared, x, positions, cfg)
-                return y.astype(x.dtype)
-            return jax.lax.cond((idx + 1) % cfg.attn_every == 0, with_attn,
-                                lambda x: x, x)
-
-        def body(x, xs):
-            bp, idx = xs
-            return layer(x, bp, idx), None
-        x, _ = jax.lax.scan(body, x, (params["blocks"], jnp.arange(cfg.n_layers)))
-        aux = jnp.zeros((), jnp.float32)
-    else:
-        raise ValueError(cfg.arch_type)
-
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(cfg.compute_dtype)).astype(jnp.float32)
     return logits, aux
@@ -180,12 +281,6 @@ def _forward_unrolled(params, x, positions, cfg: ModelConfig, maybe_remat):
                 y, _ = mamba_block_fwd(bp, x, cfg)
                 return y
             x = maybe_remat(layer)(x)
-            if cfg.arch_type == "hybrid" and (i + 1) % cfg.attn_every == 0:
-                def shared_layer(x):
-                    y, _, _ = attn_block_fwd(params["shared_attn"], x,
-                                             positions, cfg)
-                    return y
-                x = maybe_remat(shared_layer)(x)
     return x, aux
 
 
@@ -201,7 +296,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
         cache["mamba"] = init_mamba_cache(cfg, batch, cfg.n_layers)
     elif cfg.arch_type == "hybrid":
         cache["mamba"] = init_mamba_cache(cfg, batch, cfg.n_layers)
-        cache["attn"] = init_kv_cache(cfg, batch, max_len, n_shared_applications(cfg))
+        cache["attn"] = init_kv_cache(cfg, batch, max_len, len(cfg.hybrid_layers))
     return cache
 
 
@@ -221,7 +316,28 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int):
         return (jax.lax.dynamic_update_slice(z, k.astype(z.dtype), (0, 0, 0, 0)),
                 jax.lax.dynamic_update_slice(z, v.astype(z.dtype), (0, 0, 0, 0)))
 
-    if not cfg.scan_layers:
+    if cfg.arch_type == "hybrid":
+        e = x
+
+        def attend(ap, h, scale):
+            return attention_forward(ap, h, positions, cfg, return_kv=True,
+                                     scale=scale)
+
+        def layer(bp, x, mc, inv):
+            t = kv = None
+            if inv is not None:
+                t, kv = shared_block(inv[0], inv[1], x, e, attend, cfg)
+                kv = place_kv(kv)
+            x, (st, tail) = mamba_block_fwd(bp, x, cfg, return_state=True, t=t)
+            return x, {"ssm": st, "conv_x": tail["x"], "conv_B": tail["B"],
+                       "conv_C": tail["C"]}, kv
+
+        x, cache["mamba"], kvs = _hybrid_stack(params, x, cfg, layer,
+                                               mcache=cache["mamba"])
+        if kvs:
+            cache["attn"] = {"k": jnp.stack([k for k, _ in kvs]),
+                             "v": jnp.stack([v for _, v in kvs])}
+    elif not cfg.scan_layers:
         x, cache = _prefill_unrolled(params, x, positions, cfg, cache, place_kv)
     elif cfg.arch_type in ("dense", "moe", "vlm", "audio"):
         def body(carry, bp):
@@ -238,35 +354,6 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int):
         x, (states, tails) = jax.lax.scan(body, x, params["blocks"])
         cache["mamba"] = {"ssm": states, "conv_x": tails["x"],
                           "conv_B": tails["B"], "conv_C": tails["C"]}
-    elif cfg.arch_type == "hybrid":
-        shared = params["shared_attn"]
-        n_sh = n_shared_applications(cfg)
-        kz = jnp.zeros((n_sh, B, Sc, cfg.n_kv_heads, cfg.hd), cfg.compute_dtype)
-        vz = jnp.zeros_like(kz)
-
-        def body(carry, xs):
-            x, ck, cv = carry
-            bp, idx = xs
-            x, st = mamba_block_fwd(bp, x, cfg, return_state=True)
-
-            def with_attn(args):
-                x, ck, cv = args
-                y, _, kv = attn_block_fwd(shared, x, positions, cfg, return_kv=True)
-                k_full, v_full = place_kv(kv)
-                j = idx // cfg.attn_every
-                ck = jax.lax.dynamic_update_index_in_dim(ck, k_full, j, 0)
-                cv = jax.lax.dynamic_update_index_in_dim(cv, v_full, j, 0)
-                return y, ck, cv
-
-            x, ck, cv = jax.lax.cond((idx + 1) % cfg.attn_every == 0, with_attn,
-                                     lambda a: a, (x, ck, cv))
-            return (x, ck, cv), st
-
-        (x, ks, vs), (states, tails) = jax.lax.scan(
-            body, (x, kz, vz), (params["blocks"], jnp.arange(cfg.n_layers)))
-        cache["mamba"] = {"ssm": states, "conv_x": tails["x"],
-                          "conv_B": tails["B"], "conv_C": tails["C"]}
-        cache["attn"] = {"k": ks, "v": vs}
 
     cache["pos"] = jnp.asarray(S, jnp.int32)
     x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
@@ -290,12 +377,6 @@ def _prefill_unrolled(params, x, positions, cfg: ModelConfig, cache, place_kv):
             tx.append(tail["x"])
             tB.append(tail["B"])
             tC.append(tail["C"])
-            if cfg.arch_type == "hybrid" and (i + 1) % cfg.attn_every == 0:
-                x, _, kv = attn_block_fwd(params["shared_attn"], x, positions,
-                                          cfg, return_kv=True)
-                k, v = place_kv(kv)
-                attn_k.append(k)
-                attn_v.append(v)
     if attn_k:
         cache["attn"] = {"k": jnp.stack(attn_k), "v": jnp.stack(attn_v)}
     if states:
@@ -310,7 +391,29 @@ def decode_step(params, cache, tokens, cfg: ModelConfig):
     x = params["embed"][tokens].astype(cfg.compute_dtype)
     new_cache = dict(cache)
 
-    if not cfg.scan_layers:
+    if cfg.arch_type == "hybrid":
+        e = x
+
+        def layer(bp, x, mc, inv):
+            t = kv = None
+            if inv is not None:
+                ck, cv = inv[2]["k"], inv[2]["v"]
+
+                def attend(ap, h, scale):
+                    out, nk, nv = decode_attention(ap, h, ck, cv, pos, cfg, scale)
+                    return out, (nk, nv)
+                t, kv = shared_block(inv[0], inv[1], x, e, attend, cfg)
+            h_in = x if t is None else x + t
+            h, mc = mamba2_decode(bp["mamba"], rms_norm(h_in, bp["ln"], cfg.norm_eps),
+                                  mc, cfg)
+            return x + h, mc, kv
+
+        x, new_cache["mamba"], kvs = _hybrid_stack(
+            params, x, cfg, layer, mcache=cache["mamba"], kv=cache["attn"])
+        if kvs:
+            new_cache["attn"] = {"k": jnp.stack([k for k, _ in kvs]),
+                                 "v": jnp.stack([v for _, v in kvs])}
+    elif not cfg.scan_layers:
         x, new_cache = _decode_unrolled(params, cache, x, pos, cfg)
     elif cfg.arch_type in ("dense", "moe", "vlm", "audio"):
         def body(x, xs):
@@ -336,40 +439,6 @@ def decode_step(params, cache, tokens, cfg: ModelConfig):
             return x + h, nc
         x, nmamba = jax.lax.scan(body, x, (params["blocks"], cache["mamba"]))
         new_cache["mamba"] = nmamba
-    elif cfg.arch_type == "hybrid":
-        shared = params["shared_attn"]
-
-        def body(carry, xs):
-            x, ck, cv = carry
-            bp, idx, cslice = xs
-            h, nc = mamba2_decode(bp["mamba"], rms_norm(x, bp["ln"], cfg.norm_eps),
-                                  cslice, cfg)
-            x = x + h
-
-            def with_attn(args):
-                x, ck, cv = args
-                j = idx // cfg.attn_every
-                ckj = jax.lax.dynamic_index_in_dim(ck, j, 0, keepdims=False)
-                cvj = jax.lax.dynamic_index_in_dim(cv, j, 0, keepdims=False)
-                h, nk, nv = decode_attention(shared["attn"],
-                                             rms_norm(x, shared["ln1"], cfg.norm_eps),
-                                             ckj, cvj, pos, cfg)
-                x = x + h
-                h2 = rms_norm(x, shared["ln2"], cfg.norm_eps)
-                x = x + swiglu(h2, **shared["mlp"])
-                ck = jax.lax.dynamic_update_index_in_dim(ck, nk, j, 0)
-                cv = jax.lax.dynamic_update_index_in_dim(cv, nv, j, 0)
-                return x, ck, cv
-
-            x, ck, cv = jax.lax.cond((idx + 1) % cfg.attn_every == 0, with_attn,
-                                     lambda a: a, (x, ck, cv))
-            return (x, ck, cv), nc
-
-        (x, ks, vs), nmamba = jax.lax.scan(
-            body, (x, cache["attn"]["k"], cache["attn"]["v"]),
-            (params["blocks"], jnp.arange(cfg.n_layers), cache["mamba"]))
-        new_cache["mamba"] = nmamba
-        new_cache["attn"] = {"k": ks, "v": vs}
 
     new_cache["pos"] = pos + 1
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -381,7 +450,6 @@ def _decode_unrolled(params, cache, x, pos, cfg: ModelConfig):
     """Python-unrolled decode step (roofline probes)."""
     new_cache = dict(cache)
     ks, vs, mslices = [], [], []
-    n_attn_seen = 0
     for i in range(cfg.n_layers):
         bp = _layer_slice(params["blocks"], i)
         if cfg.arch_type in ("dense", "moe", "vlm", "audio"):
@@ -403,18 +471,6 @@ def _decode_unrolled(params, cache, x, pos, cfg: ModelConfig):
                                   cs, cfg)
             x = x + h
             mslices.append(nc)
-            if cfg.arch_type == "hybrid" and (i + 1) % cfg.attn_every == 0:
-                j = n_attn_seen
-                n_attn_seen += 1
-                sh = params["shared_attn"]
-                h, nk, nv = decode_attention(sh["attn"],
-                                             rms_norm(x, sh["ln1"], cfg.norm_eps),
-                                             cache["attn"]["k"][j],
-                                             cache["attn"]["v"][j], pos, cfg)
-                x = x + h
-                x = x + swiglu(rms_norm(x, sh["ln2"], cfg.norm_eps), **sh["mlp"])
-                ks.append(nk)
-                vs.append(nv)
     if ks:
         new_cache["attn"] = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
     if mslices:

@@ -306,5 +306,5 @@ def test_long_context_configs():
     assert dense.sliding_window == 8192
     ssm = for_shape(get_config("mamba2-130m"), shp)
     assert ssm.sliding_window == 0          # recurrent: native long context
-    hyb = for_shape(get_config("zamba2-2.7b"), shp)
+    hyb = for_shape(get_config("zamba2-7b"), shp)
     assert hyb.sliding_window == 8192       # shared attn block needs the ring
