@@ -118,7 +118,9 @@ def kernels_phase(seed, shapes):
                 ref.quantize_pack_fused_ref, static_argnums=3
             )(g, qh, R, bits)
             bitwise(
-                f"quantize_pack_fused b={bits} codes", pk[: n // cpb], rpk[: n // cpb]
+                f"quantize_pack_fused b={bits} codes",
+                pk.reshape(-1)[: n // cpb],
+                rpk[: n // cpb],
             )
             bitwise(f"quantize_pack_fused b={bits} delta", dl, rdl)
             bitwise(f"quantize_pack_fused b={bits} q_new", qn, rqn)
@@ -336,7 +338,11 @@ def main():
         )
         check(run.step.wire_backend == "fused", "the step ran the fused backend")
         check("tpu_custom_call" in hlo, "the compiled step holds tpu_custom_call")
-        log(f"  tpu_custom_call in the step: {hlo.count('tpu_custom_call')}")
+        log(
+            f"  tpu_custom_call in the step: {hlo.count('tpu_custom_call')}; "
+            f"quantize passes a leaf a round: "
+            f"{run.step.wire_layout['quantize_passes']}"
+        )
         log(f"  peak device memory {peak_bytes()} bytes")
         del run, hlo
         gc.collect()

@@ -227,7 +227,7 @@ def publish(cfg: PublishConfig, state: PublisherState,
             rt = backend.roundtrip(g, q, width, per_leaf=True,
                                    with_payload=True)
             qn_leaves.append(rt.q_new)
-            payloads.append(rt.payload[0])
+            payloads.append(rt.payload[0].reshape(-1))   # flat bytes
             radii_out.append(rt.R_tree)
         bits = float(upload_bits(p, width, n_radii=n_leaves,
                                  bit_sidecar=cfg.bit_schedule is not None))

@@ -338,6 +338,10 @@ class WorkerOut(NamedTuple):
                             # it; drives qhat/eps/clock/estimator commits)
     defense_new: DefenseState = DefenseState(None, None, None)  # updated
                             # validation state (None-gated pass-through)
+    payload: Optional[list] = None  # the roundtrip's per-leaf packed codes
+                            # (with_payload only: the whole-leaf wire ships
+                            # them as the quantizer left them)
+    payload_R: Pytree = None  # their per-leaf radii (with_payload only)
 
 
 def worker_update(grad_m: Pytree, qhat_m: Pytree, eps_hat_sq_m, clock_m,
@@ -345,7 +349,7 @@ def worker_update(grad_m: Pytree, qhat_m: Pytree, eps_hat_sq_m, clock_m,
                   cfg: StrategyConfig, step=None, lazy_m=None,
                   R_anchor_m=None, params=None, grad_stale_m=None,
                   avail_m=None, error_m=None, ckey_m=None, defense_m=None,
-                  flip_m=None, fkey_m=None):
+                  flip_m=None, fkey_m=None, with_payload=False):
     """One worker's bit-width selection + quantize + skip decision.
 
     ``lazy_m`` is this worker's :class:`~repro.core.lazy_rules.LazyState`
@@ -362,6 +366,10 @@ def worker_update(grad_m: Pytree, qhat_m: Pytree, eps_hat_sq_m, clock_m,
     :class:`~repro.core.defense.DefenseState` slice (required when
     ``cfg.defense.active``); ``flip_m`` / ``fkey_m`` are the wire-fault
     mask bit and flip-position key (``core/faults.py``, bitflip kind only).
+    ``with_payload`` (fixed-width quantized kinds) keeps the roundtrip's
+    packed payload and its per-leaf radii in ``WorkerOut.payload`` /
+    ``payload_R``, so that the sharded step's whole-leaf wire ships the
+    codes this round's quantize made instead of quantizing again.
 
     Masking discipline — ONE code path for every way a payload fails to
     commit.  Two bits gate the state commits:
@@ -415,6 +423,14 @@ def worker_update(grad_m: Pytree, qhat_m: Pytree, eps_hat_sq_m, clock_m,
     n_sidecars = (len(jax.tree_util.tree_leaves(grad_m))
                   if cfg.per_leaf_radius else 1)
     backend = get_backend(cfg.wire_backend)
+    if with_payload:
+        # the payload ships as the quantizer emitted it: no stage below may
+        # change the delta it encodes
+        assert (cfg.quantized and not cfg.adaptive and not cfg.compressed
+                and not cfg.faults.wire_faulty
+                and cfg.defense.clip_mult == 0.0), \
+            "with_payload needs the plain fixed-width quantized roundtrip"
+    payload = payload_R = None
     if cfg.adaptive:
         sched = cfg.bit_schedule
         step_ = jnp.zeros((), jnp.int32) if step is None else step
@@ -447,9 +463,11 @@ def worker_update(grad_m: Pytree, qhat_m: Pytree, eps_hat_sq_m, clock_m,
         width_m = jnp.full((), float(cfg.effective_bits), jnp.float32)
     elif cfg.quantized:
         rt = backend.roundtrip(g_eff, qhat_m, cfg.effective_bits,
-                               cfg.per_leaf_radius)
+                               cfg.per_leaf_radius, with_payload=with_payload)
         q_new, delta, R = rt.q_new, rt.delta, rt.R_max
         R_tree = rt.R_tree      # the wire-fault layer flips codes per leaf
+        if with_payload:
+            payload, payload_R = rt.payload, rt.R_tree
         # the fused backend emits both criterion moments as in-pass partial
         # sums; the reference backend spends two extra sweeps on them
         err_sq, innovation_sq = rt.err_sq, rt.innovation_sq
@@ -575,7 +593,7 @@ def worker_update(grad_m: Pytree, qhat_m: Pytree, eps_hat_sq_m, clock_m,
         error_new = error_m
     return WorkerOut(delta_masked, qhat_new, eps_hat_sq_new, clock_new,
                      uploaded, bits_m, R, width_m, lazy_new, R_anchor_new,
-                     error_new, committed, defense_new)
+                     error_new, committed, defense_new, payload, payload_R)
 
 
 # ---------------------------------------------------------------------------

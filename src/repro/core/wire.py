@@ -74,17 +74,19 @@ class WireRoundtrip(NamedTuple):
     err_sq: jax.Array       # ||grad - q_new||^2  (criterion eps term)
     innovation_sq: jax.Array  # ||delta||^2       (criterion LHS)
     payload: Optional[list]   # per-leaf packed uint8 codes (with_payload only);
-                              # layout is backend-specific (the fused payload is
-                              # BLOCK-padded), byte semantics are shared
+                              # layout is backend-specific (the Pallas payload
+                              # is the kernel's BLOCK-padded rows), byte
+                              # semantics are shared: the bytes in order are
+                              # the flat payload
 
 
 class WireBackend:
     """Interface: radius reduction, quantize roundtrip, server dequant-acc.
 
-    The per-LEAF primitives (``leaf_absmax`` / ``leaf_quantize_packed`` /
-    ``leaf_quantize`` / ``leaf_quantize_adaptive``) are the streamed
-    sharded wire's hot loop (launch/train.py ``_packed_aggregate`` touches
-    one leaf at a time).
+    The per-LEAF primitives (``leaf_absmax`` / ``leaf_quantize`` /
+    ``leaf_quantize_adaptive``) are the streamed axis codec's hot loop
+    (launch/train.py ``_packed_aggregate`` touches one leaf at a time); the
+    whole-leaf wire ships :meth:`roundtrip`'s payload instead.
     Their base-class bodies below ARE the reference expressions — verbatim
     :mod:`repro.core.quantize` / :mod:`repro.core.adaptive` calls — so every
     backend inherits bit-identical wire content by code sharing; subclasses
@@ -125,20 +127,6 @@ class WireBackend:
         codes = quantize_codes(d, R, bits)
         delta = dequantize_innovation(codes, R, bits)
         return codes, delta
-
-    @scoped(WIRE_QUANTIZE)
-    def leaf_quantize_packed(self, g, qh, R, bits: int):
-        """``(payload, delta)`` for one leaf at one static width — the
-        send-side pass-2 sweep of the whole-leaf sharded wire: the codes
-        leave packed in the flat layout (:func:`pack_flat`), and
-        ``dequant_acc`` decodes the payloads stacked over workers.  The
-        payload's padding is the backend's own (the fused kernel's is
-        BLOCK-padded rows), its byte semantics are shared; ``delta`` is f32
-        and leaf-shaped."""
-        codes, delta = self.leaf_quantize(g, qh, R, bits)
-        with jax.named_scope(WIRE_CODEC):
-            payload = pack_flat(codes, bits)
-        return payload, delta
 
     @scoped(WIRE_QUANTIZE)
     def leaf_quantize_adaptive(self, g, qh, R, grid, onehot, t_sel):
@@ -206,8 +194,9 @@ class ReferenceWire(WireBackend):
         innovation_sq = tree_sq_norm(delta)
         payload = None
         if with_payload:
-            payload = [pack_flat(q, bits)
-                       for q in jax.tree_util.tree_leaves(qints)]
+            with jax.named_scope(WIRE_CODEC):
+                payload = [pack_flat(q, bits)
+                           for q in jax.tree_util.tree_leaves(qints)]
         return WireRoundtrip(q_new, delta, R_tree, R_max, err_sq,
                              innovation_sq, payload)
 
@@ -245,7 +234,10 @@ def _fused_leaf_jnp(g, qh, R, bits, with_payload):
     err = gf - qn
     err_sq = jnp.sum(err * err)
     inn_sq = jnp.sum(delta * delta)
-    payload = pack_flat(q, bits) if with_payload else None
+    payload = None
+    if with_payload:
+        with jax.named_scope(WIRE_CODEC):
+            payload = pack_flat(q, bits)
     return delta, qn, err_sq, inn_sq, payload
 
 
@@ -307,14 +299,6 @@ class FusedWire(WireBackend):
             from repro.kernels import absmax
             return absmax(g, qh)
         return super().leaf_absmax(g, qh)
-
-    @scoped(WIRE_QUANTIZE)
-    def leaf_quantize_packed(self, g, qh, R, bits):
-        if g.size and self._use_pallas():
-            from repro.kernels import quantize_pack
-            payload, delta = quantize_pack(g, qh, R, bits)
-            return payload, delta.reshape(g.shape)
-        return super().leaf_quantize_packed(g, qh, R, bits)
 
     @scoped(WIRE_QUANTIZE)
     def leaf_quantize_adaptive(self, g, qh, R, grid, onehot, t_sel):

@@ -67,9 +67,12 @@ def quantize_pack_fused(grad, qhat, R, bits: int, *,
     """Pass 2: fused quantize+pack with moment side-outputs.
 
     grad/qhat f32 [n], R scalar.  Returns ``(packed uint8
-    [ceil(n/blk)*blk*bits/8], delta f32 [n], q_new f32 [n], err_sq,
-    innovation_sq)`` where the scalar moments are the block-partial sums of
-    ||grad - q_new||^2 and ||delta||^2 over the n real elements.
+    [ceil(n/blk)*blk/ROW, ROW*bits/8], delta f32 [n], q_new f32 [n], err_sq,
+    innovation_sq)``: the payload in the kernel's rows, as
+    :func:`quantize_pack` emits it (:func:`dequant_acc` takes them stacked
+    over workers as they are; callers that want flat bytes flatten them),
+    and the scalar moments, the block-partial sums of ||grad - q_new||^2
+    and ||delta||^2 over the n real elements.
     """
     if interpret is None:
         interpret = _on_cpu()
@@ -138,7 +141,7 @@ def quantize_pack(grad, qhat, R, bits: int, *, interpret: bool | None = None):
     in order are the flat little-end-first payload).  The payload-only
     kernel: no q_new/moment outputs, so payload-only callers don't pay
     their VMEM writes (use quantize_pack_fused when the criterion moments
-    are wanted too).
+    are wanted too; its payload is these rows, byte for byte).
     """
     if interpret is None:
         interpret = _on_cpu()
@@ -175,7 +178,8 @@ def dequant_acc(packed, R, keep, bits: int, n: int, acc=None, *,
     """Server-side unpack+dequant+accumulate over the worker dim.
 
     ``packed``: uint8 [W, nbytes], or [W, rows, ROW*bits/8] as
-    :func:`quantize_pack` emits them, BLOCK-padded either way.  Returns
+    :func:`quantize_pack` and :func:`quantize_pack_fused` emit them,
+    BLOCK-padded either way.  Returns
     f32 [n].  ``acc`` (optional f32 [n], e.g. the server aggregate) is
     folded into the same pass: out = acc + sum_w keep_w * delta_w.
     """

@@ -234,9 +234,11 @@ def quantize_pack_pallas(grad, qhat, R, bits: int, n_valid: int, *,
     """grad, qhat: flat f32 [n] (n % BLOCK == 0), R: f32 [1],
     n_valid: static count of real (non-pad) elements.
 
-    Returns ``(packed uint8 [n*bits/8], delta f32 [n], q_new f32 [n],
-    err_part, inn_part)`` — the partials (f32 [n//BLOCK, 8, ROW]) are
-    masked to the first ``n_valid`` elements; their sum gives
+    Returns ``(packed uint8 [n // ROW, ROW*bits/8], delta f32 [n], q_new
+    f32 [n], err_part, inn_part)`` — the payload stays in the kernel's rows
+    (on the TPU a 1-D uint8 reshape of them is a relayout of its own; their
+    bytes in order are the flat payload); the partials (f32 [n//BLOCK, 8,
+    ROW]) are masked to the first ``n_valid`` elements; their sum gives
     ||grad - q_new||^2 and ||delta||^2.
     """
     n = grad.shape[0]
@@ -252,8 +254,7 @@ def quantize_pack_pallas(grad, qhat, R, bits: int, n_valid: int, *,
          jax.ShapeDtypeStruct((n // ROW, ROW), jnp.float32),
          _part_shape(nb), _part_shape(nb)],
         interpret, _rows(grad), _rows(qhat), R)
-    return (packed.reshape(-1), delta.reshape(-1), q_new.reshape(-1),
-            err, inn)
+    return packed, delta.reshape(-1), q_new.reshape(-1), err, inn
 
 
 def _quantize_pack_payload_kernel(bits, g_ref, qh_ref, R_ref, packed_ref,
@@ -267,9 +268,9 @@ def _quantize_pack_payload_kernel(bits, g_ref, qh_ref, R_ref, packed_ref,
 def quantize_pack_payload_pallas(grad, qhat, R, bits: int, *,
                                  interpret: bool = True):
     """Payload-only variant of the pass-2 kernel: packed codes + delta, no
-    q_new/moment outputs — for callers that only ship the wire payload and
-    should not pay the extra VMEM writes (the sharded step's whole-leaf
-    wire, benchmarks, the roundtrip tests).
+    q_new/moment outputs — for callers that only want the wire payload
+    (benchmarks, the roundtrip tests: the pinned-width oracle of the fused
+    kernel's payload).
 
     Returns ``(packed uint8 [n // ROW, ROW*bits/8], delta f32 [n])``: the
     payload stays in the kernel's rows, since on the TPU a 1-D uint8

@@ -25,9 +25,10 @@ Packed wire format (per worker, per round):
   sidecars exchanged once per round: the radius ``R`` (f32 per leaf for
   ``per_leaf_radius``, else one global f32) and the skip-mask bit.  Where
   every leaf is whole on its device (no mesh axis left to GSPMD) the
-  payload is the flattened leaf's, as the send kernel packs it, and the
-  decode kernel sums it; elsewhere codes are packed along the leaf's last
-  dim when it divides 8/b (odd last dims ship raw uint8 codes).
+  payload is the flattened leaf's, as the lazy rule's send kernel packed
+  it (one quantize a round), and the decode kernel sums it; elsewhere
+  codes are quantized again and packed along the leaf's last dim when it
+  divides 8/b (odd last dims ship raw uint8 codes).
 * **adaptive** (``bit_schedule`` radius/budget, core/adaptive.py) — each
   worker additionally announces its selected width ``b_m^k`` as a third
   sidecar, and every receiver decodes with the sender's tau(b_m^k).  The
@@ -168,9 +169,36 @@ def exchange_mode(n_workers: int) -> str:
     return "permute" if n_workers == 2 else "gather"
 
 
+def _flat_aggregate(payloads, radii, skip_mask, grads,
+                    strategy: StrategyConfig, worker_axes):
+    """The whole-leaf wire (fixed width, every leaf whole on its device):
+    the exchange of the payloads and radii that the lazy rule's roundtrip
+    already made (``worker_update(..., with_payload=True)``), so each leaf
+    gets one radius pass and one pass-2 sweep a round.  Per leaf, the
+    payload and its radius are gathered over the workers at every W (at
+    W=2 a two-term sum is the same bits in either order, so the axis
+    codec's swap would buy nothing), and ``dequant_acc`` unpacks,
+    dequantizes and masked-sums the workers' payloads in one pass; no
+    leaf-shaped code array exists.  Returns the sum of innovations."""
+    backend = get_backend(strategy.wire_backend)
+    bits = strategy.effective_bits
+    keep = jnp.logical_not(skip_mask).astype(jnp.float32)
+    keep_w = jax.lax.all_gather(keep, worker_axes)                  # [W]
+    g_leaves, treedef = jax.tree_util.tree_flatten(grads)
+    agg = []
+    for payload, R, g in zip(payloads, jax.tree_util.tree_leaves(radii),
+                             g_leaves):
+        payload_w = jax.lax.all_gather(payload, worker_axes)
+        R_w = jax.lax.all_gather(R, worker_axes)
+        total = backend.dequant_acc(payload_w, R_w, keep_w, bits, g.size)
+        with jax.named_scope(WIRE_CODEC):
+            agg.append(total.reshape(g.shape))
+    return jax.tree_util.tree_unflatten(treedef, agg)
+
+
 def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
-                      worker_axes, pspecs=None, width=None, flat=False):
-    """The packed-uint8 wire, **streamed one leaf at a time**: per leaf,
+                      worker_axes, pspecs=None, width=None):
+    """The axis-packed uint8 wire, **streamed one leaf at a time**: per leaf,
     innovation -> quantize -> pack -> exchange -> dequantize -> masked sum
     (plus that leaf's local ``q_new`` reconstruction) before the next leaf
     is touched.  Returns (sum_of_innovations, q_new_tree).
@@ -195,16 +223,12 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
     the buffer is provisioned at max(grid), and the width rides along as a
     sidecar so receivers decode with the sender's tau (see module docstring).
 
-    ``flat`` (fixed width, every leaf whole on its device) switches on the
-    whole-leaf wire: the backend's ``leaf_quantize_packed`` payload ships
-    as it is, and ``dequant_acc`` unpacks, dequantizes and masked-sums the
-    workers' payloads in one pass; no leaf-shaped code array exists.
-    Otherwise the axis-packed codec below keeps each leaf's shape.
+    The codec keeps each leaf's shape; where every leaf is whole on its
+    device and the width is fixed, :func:`_flat_aggregate` runs instead.
     """
     from repro.models.layers import maybe_constrain
     per_leaf = strategy.per_leaf_radius
     adaptive = width is not None
-    assert not (flat and adaptive), "the whole-leaf wire has a fixed width"
     if adaptive:
         grid = strategy.bit_schedule.grid
         onehot = (jnp.asarray(grid, jnp.float32) == width).astype(jnp.float32)
@@ -223,12 +247,12 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
     # collective per parameter tensor)
     _perm2 = [(0, 1), (1, 0)]
     t_self = tau_of_selection(grid, onehot) if adaptive else None
-    if use_gather or flat:
+    if use_gather:
         keep_w = jax.lax.all_gather(keep, worker_axes)              # [W]
         if adaptive:
             width_w = jax.lax.all_gather(width, worker_axes)        # [W] sidecar
             tau_w = tau_of_width(grid, width_w)                     # [W]
-    if use_permute and not flat:
+    if use_permute:
         peer_keep = jax.lax.ppermute(keep, worker_axes, _perm2)
         if adaptive:
             t_peer = jax.lax.ppermute(t_self, worker_axes, _perm2)
@@ -263,16 +287,6 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
         delta = (2.0 * t * Rb * codes.astype(jnp.float32) - Rb)
         delta = jnp.where(Rb > 0, delta, 0.0) * kb
         return jnp.sum(delta, axis=0)
-
-    def flat_dequant_sum(payload, R, orig):
-        # the whole-leaf wire: the payloads and radii gathered over workers
-        # at every W (at W=2 a two-term sum is the same bits in either
-        # order, so the swap's ppermute would buy nothing)
-        payload_w = jax.lax.all_gather(payload, worker_axes)
-        R_w = jax.lax.all_gather(R, worker_axes)
-        total = backend.dequant_acc(payload_w, R_w, keep_w, bits, orig.size)
-        with jax.named_scope(WIRE_CODEC):
-            return total.reshape(orig.shape)
 
     def permute_dequant_sum(q, R, orig, spec):
         # two-worker wire (pods): a single collective-permute payload
@@ -320,16 +334,10 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
     def stream_leaf(g, qh, R, spec):
         # the streamed hot path: this leaf's codes, payload and dequantized
         # delta are dead before the next leaf starts.  The send-side sweep
-        # is the backend's pass-2 leaf primitive.  Whole-leaf wire: the
-        # payload kernel's rows off-CPU, the flat reference payload on CPU.
-        # Axis codec: the adaptive codes+delta Pallas kernel off-CPU, the
-        # reference expressions otherwise (every fixed-width sweep, and the
-        # adaptive one on CPU).
-        if flat:
-            payload, delta_local = backend.leaf_quantize_packed(g, qh, R,
-                                                                bits)
-            agg = flat_dequant_sum(payload, R, g)
-        elif adaptive:
+        # is the backend's pass-2 leaf primitive: the adaptive codes+delta
+        # Pallas kernel off-CPU, the reference expressions otherwise (every
+        # fixed-width sweep, and the adaptive one on CPU).
+        if adaptive:
             q, delta_local = backend.leaf_quantize_adaptive(
                 g, qh, R, grid, onehot, t_sel)
             agg = leaf_fn(q, R, g, spec)
@@ -422,11 +430,16 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
         # is whole on its device) and the width is fixed; else the axis codec
         flat_wire = not strategy.adaptive and manual == set(mesh.axis_names)
         sizes = [leaf.size for leaf in jax.tree.leaves(params_abs)]
-        wire_layout = {"flat": (0, 0), "axis": (0, 0)}
+        # pass-2 sweeps a leaf gets per round: the whole-leaf wire ships the
+        # lazy rule's payload, the axis codec quantizes again
+        wire_layout = {"flat": (0, 0), "axis": (0, 0),
+                       "quantize_passes": 1 if flat_wire else 2}
         wire_layout["flat" if flat_wire else "axis"] = (len(sizes), sum(sizes))
         logging.getLogger(__name__).info(
             "packed wire: %d leaves (%d parameters) flat, %d (%d) on the "
-            "axis codec", *wire_layout["flat"], *wire_layout["axis"])
+            "axis codec; %d quantize pass(es) a leaf a round",
+            *wire_layout["flat"], *wire_layout["axis"],
+            wire_layout["quantize_passes"])
 
     shared_block = None
     if cfg.arch_type == "hybrid":
@@ -524,7 +537,8 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
                                step=comm.step, lazy_m=lazy,
                                R_anchor_m=R_anchor, params=params,
                                grad_stale_m=grads_stale, avail_m=avail,
-                               error_m=error, ckey_m=ckey, defense_m=defense)
+                               error_m=error, ckey_m=ckey, defense_m=defense,
+                               with_payload=flat_wire)
         (delta_masked, qhat_new, eps_hat_sq_new, clock_new, uploaded,
          bits_m, width_m) = (wu.delta_masked, wu.qhat_new, wu.eps_hat_sq_new,
                              wu.clock_new, wu.uploaded, wu.bits_m, wu.width_m)
@@ -539,10 +553,13 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
                 # like a lazy skip (its bits_m still pay: the payload was
                 # sent)
                 skip = jnp.logical_not(wu.committed)
-                agg_delta, _ = _packed_aggregate(
-                    grads, qhat, skip, strategy, wa, pspecs=grad_pspecs,
-                    width=width_m if strategy.adaptive else None,
-                    flat=flat_wire)
+                if flat_wire:
+                    agg_delta = _flat_aggregate(wu.payload, wu.payload_R,
+                                                skip, grads, strategy, wa)
+                else:
+                    agg_delta, _ = _packed_aggregate(
+                        grads, qhat, skip, strategy, wa, pspecs=grad_pspecs,
+                        width=width_m if strategy.adaptive else None)
 
             agg = jax.tree.map(lambda a, d: a.astype(jnp.float32) + d,
                                comm.server_agg, agg_delta)
@@ -615,8 +632,9 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
         return TrainState(new_params, new_opt, new_comm, new_step), metrics
 
     # introspection: the wire backend the sharded step runs, and on the
-    # packed wire how many leaves and parameters take each layout
-    # ({"flat": (leaves, params), "axis": (leaves, params)}; None on float)
+    # packed wire how many leaves and parameters take each layout and how
+    # many pass-2 sweeps a leaf gets a round ({"flat": (leaves, params),
+    # "axis": (leaves, params), "quantize_passes": n}; None on float)
     step.wire_backend = get_backend(strategy.wire_backend).name
     step.wire_layout = wire_layout
     # a hybrid model's shares and shared-block count (None otherwise)

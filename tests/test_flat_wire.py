@@ -13,7 +13,13 @@ some of whose last dims are odd (three SSD heads), and checks:
   and for the fused backend's Pallas kernels (interpret mode) on the
   gather at 4;
 * the step's layout counter reads every leaf flat on a ``model=1`` mesh,
-  and none on a ``model=2`` mesh or with an adaptive schedule.
+  and none on a ``model=2`` mesh or with an adaptive schedule;
+* at trace time, counters wrapped round the backends' radius and pass-2
+  primitives: on the whole-leaf wire, on ``(4, 1)`` and ``(1, 1)``, each
+  non-empty leaf gets one radius pass and one pass-2 sweep a round (the
+  exchange ships the lazy rule's payload), and the layout's pass count
+  reads 1; on the axis codec (``model=2``) it gets two of each, and the
+  count reads 2, as it does on the adaptive wire.
 """
 import json
 import os
@@ -31,6 +37,8 @@ BACKENDS = ("reference", "fused")
 MESHES = [(4, b) for b in BITS] + [(2, 4)]
 CASES = [(W, backend, b) for W, b in MESHES for backend in BACKENDS]
 IDS = [f"W{W}-{backend}-b{b}" for W, backend, b in CASES]
+# (workers, model) meshes whose traced step the pass counters read
+PASS_MESHES = [(4, 1), (1, 1), (4, 2)]
 
 _SCRIPT = r"""
 import dataclasses, json, os, sys
@@ -47,7 +55,7 @@ from repro.launch.train import (init_train_state, make_train_step,
 from repro.models import init_params
 from repro.optim import sgd
 
-MESHES, BACKENDS = json.loads(sys.argv[1])
+MESHES, BACKENDS, PASS_MESHES = json.loads(sys.argv[1])
 cfg = dataclasses.replace(smoke_config(get_config("mamba2-130m")),
                           n_layers=1, d_model=48, vocab=256)
 opt = sgd()
@@ -115,6 +123,53 @@ out["layout-model2"] = make_train_step(
 out["layout-adaptive"] = make_train_step(
     cfg, mesh_of(4), adaptive, opt, lr=1e-2, worker_axes=wa,
     wire="packed").wire_layout
+
+# how many radius passes and pass-2 sweeps each leaf gets in one traced
+# round: counters round the radius primitives (the backends' leaf_absmax,
+# the reference's tree innovation) and the pass-2 ones (the per-leaf code
+# map, the fused backend's jnp sweep)
+import collections
+import repro.core.quantize as Q
+import repro.core.wire as Wm
+counts = collections.Counter()
+
+
+def counted(kind, fn, arg):
+    def wrapped(*a, **k):
+        for x in jax.tree.leaves(a[arg]):
+            if x.size:
+                counts[kind, tuple(x.shape)] += 1
+        return fn(*a, **k)
+    return wrapped
+
+
+Wm.WireBackend.leaf_absmax = counted("radius", Wm.WireBackend.leaf_absmax, 1)
+Q.innovation = counted("radius", Q.innovation, 0)
+Q.quantize_codes = counted("pass2", Q.quantize_codes, 0)
+Wm.quantize_codes = counted("pass2", Wm.quantize_codes, 0)
+Wm._fused_leaf_jnp = counted("pass2", Wm._fused_leaf_jnp, 0)
+leaf_shapes = collections.Counter(
+    tuple(l.shape) for l in jax.tree.leaves(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg))) if l.size)
+for data, model in PASS_MESHES:
+    mesh = mesh_of(data, model)
+    for backend in BACKENDS:
+        strat = fixed._replace(wire_backend=backend)
+        s = jax.eval_shape(lambda: init_train_state(
+            jax.random.PRNGKey(0), cfg, mesh, strat, opt, wa))
+        batch = jax.eval_shape(lambda: synthetic_lm_batch(
+            jax.random.PRNGKey(1), 8, 32, cfg.vocab))
+        step = make_train_step(cfg, mesh, strat, opt, lr=1e-2,
+                               worker_axes=wa, wire="packed")
+        counts.clear()
+        jax.jit(step).trace(s, batch)
+        per_leaf = {kind: sorted({counts[kind, shp] / n
+                                  for shp, n in leaf_shapes.items()})
+                    for kind in ("radius", "pass2")}
+        per_leaf["stray"] = sum(v for (_, shp), v in counts.items()
+                                if shp not in leaf_shapes)
+        per_leaf["quantize_passes"] = step.wire_layout["quantize_passes"]
+        out[f"passes-{data}x{model}-{backend}"] = per_leaf
 print("RESULT " + json.dumps(out))
 """
 
@@ -126,7 +181,7 @@ def result():
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, "-c", _SCRIPT,
-         json.dumps([MESHES, BACKENDS])],
+         json.dumps([MESHES, BACKENDS, PASS_MESHES])],
         env=env, capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stderr[-4000:]
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
@@ -152,10 +207,25 @@ def test_packed_equals_float_on_the_kernels(result):
 @pytest.mark.parametrize("W,backend,bits", CASES, ids=IDS)
 def test_every_leaf_flat_on_model_1(result, W, backend, bits):
     layout = result[f"layout-W{W}-{backend}-b{bits}"]
-    assert layout == {"flat": result["leaves"], "axis": [0, 0]}
+    assert layout == {"flat": result["leaves"], "axis": [0, 0],
+                      "quantize_passes": 1}
 
 
 @pytest.mark.parametrize("case", ["model2", "adaptive"])
 def test_no_leaf_flat_elsewhere(result, case):
     assert result[f"layout-{case}"] == {"flat": [0, 0],
-                                        "axis": result["leaves"]}
+                                        "axis": result["leaves"],
+                                        "quantize_passes": 2}
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("mesh", PASS_MESHES,
+                         ids=[f"{d}x{m}" for d, m in PASS_MESHES])
+def test_one_quantize_a_round_on_the_flat_wire(result, mesh, backend):
+    """Whole-leaf wire (model=1): each non-empty leaf's radius and pass-2
+    sweep run once a round; the axis codec (model=2) runs them twice."""
+    data, model = mesh
+    passes = 1 if model == 1 else 2
+    assert result[f"passes-{data}x{model}-{backend}"] == {
+        "radius": [passes], "pass2": [passes], "stray": 0,
+        "quantize_passes": passes}

@@ -30,8 +30,9 @@ def _onehot(sel):
 
 
 def _unpack(packed, bits, n):
-    """First n codes from a packed byte vector (little-end-first lanes)."""
-    p = np.asarray(packed)
+    """First n codes from packed bytes, flat or in the kernels' rows
+    (little-end-first lanes)."""
+    p = np.asarray(packed).reshape(-1)
     if bits == 8:
         return p[:n]
     cpb = 8 // bits
@@ -126,7 +127,7 @@ def test_quantize_pack_fused_matches_ref(bits, n):
     packed_r, delta_r, qn_r, err_r, inn_r = quantize_pack_fused_ref(g, qh, R,
                                                                     bits)
     cpb = 8 // bits
-    np.testing.assert_array_equal(np.asarray(packed[:n // cpb]),
+    np.testing.assert_array_equal(np.asarray(packed).reshape(-1)[:n // cpb],
                                   np.asarray(packed_r[:n // cpb]))
     np.testing.assert_allclose(np.asarray(delta), np.asarray(delta_r),
                                atol=1e-5)
@@ -135,6 +136,28 @@ def test_quantize_pack_fused_matches_ref(bits, n):
                                atol=1e-6)
     np.testing.assert_allclose(float(inn_sq), float(inn_r), rtol=1e-4,
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("bits", [2, 4, 8])
+@pytest.mark.parametrize("n", EDGE_SHAPES)
+def test_fused_payload_rows_are_the_payload_kernels(bits, n):
+    """The whole-leaf wire ships the fused kernel's payload where it once
+    ran the payload-only kernel again: for the same ``(g, qh, R)`` the two
+    kernels' rows are the same bytes in the same layout, and ``dequant_acc``
+    decodes them to the same bits."""
+    key = jax.random.PRNGKey(n * bits + 5)
+    g = jax.random.normal(key, (n,)) * 4
+    qh = jax.random.normal(jax.random.fold_in(key, 1), (n,))
+    R = absmax(g, qh)
+    fused, *_ = quantize_pack_fused(g, qh, R, bits)
+    payload, _ = quantize_pack(g, qh, R, bits)
+    assert fused.shape == payload.shape and fused.dtype == payload.dtype
+    np.testing.assert_array_equal(np.asarray(fused), np.asarray(payload))
+    Rw, keep = jnp.stack([R, 0.5 * R]), jnp.ones((2,))
+    got = dequant_acc(jnp.stack([fused, fused[::-1]]), Rw, keep, bits, n)
+    want = dequant_acc(jnp.stack([payload, payload[::-1]]), Rw, keep, bits,
+                       n)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("bits", [2, 4, 8])
@@ -148,7 +171,7 @@ def test_quantize_pack_fused_zero_radius_block(bits):
     assert int(jnp.max(jnp.abs(delta) > 0)) == 0
     np.testing.assert_array_equal(np.asarray(q_new), np.asarray(g))
     assert float(err_sq) == 0.0 and float(inn_sq) == 0.0
-    codes = np.asarray(packed[: n // (8 // bits)])
+    codes = np.asarray(packed).reshape(-1)[: n // (8 // bits)]
     mid = (2 ** bits) // 2
     expect = sum(mid << (bits * j) for j in range(8 // bits))
     assert (codes == expect).all()

@@ -5,8 +5,9 @@ is described, not attached, and refuses what the chip's Pallas lowering
 would refuse (block shapes off the tiling, scalar operands outside SMEM,
 too much VMEM).  Interpret-mode tests cannot see any of that.  The kernels
 are compiled as the wrappers in ``kernels/ops.py`` call them, on a leaf
-padded to whole blocks.  Every test skips where no v5e topology can be
-described.
+padded to whole blocks.  One test compiles the one-chip sharded step on the
+whole-leaf wire at a small width and counts its kernels.  Every test skips
+where no v5e topology can be described.
 """
 import jax
 import jax.numpy as jnp
@@ -105,3 +106,53 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture
+def kernels_compiled(monkeypatch):
+    """The kernels' wrappers take interpret mode on a CPU default backend,
+    which this process has: steer them to the chip's lowering, with no
+    trace cached on either side of the switch."""
+    from repro.kernels import ops
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    yield
+    jax.clear_caches()
+
+
+def test_flat_wire_step_quantizes_once(topo, no_persistent_cache,
+                                       kernels_compiled):
+    """The one-chip step on the whole-leaf wire: per non-empty leaf three
+    kernels, the radius (``absmax``), the fused pass 2 whose payload ships,
+    and the decode (``dequant_acc``).  Quantizing again for the exchange
+    made four: XLA merges the two identical radius kernels, not the two
+    pass-2 kernels."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs import get_config, smoke_config
+    from repro.core.strategy import StrategyConfig
+    from repro.core.wire import FusedWire
+    from repro.launch.train import (batch_specs, make_train_step,
+                                    train_state_specs)
+    from repro.models import init_params
+    from repro.optim import adamw
+
+    cfg = dataclasses.replace(smoke_config(get_config("mamba2-130m")),
+                              n_layers=1, d_model=64, vocab=512)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    strategy = StrategyConfig(kind="laq", bits=4, per_leaf_radius=True,
+                              wire_backend=FusedWire(lowering="pallas"))
+    opt = adamw()
+    step = make_train_step(cfg, mesh, strategy, opt, lr=1e-3,
+                           worker_axes=("data",), wire="packed")
+    assert step.wire_layout["quantize_passes"] == 1
+    hlo = jax.jit(step).lower(
+        train_state_specs(cfg, mesh, strategy, opt, ("data",)),
+        batch_specs(cfg, mesh, 2, 64)).compile().as_text()
+    leaves = [leaf for leaf in jax.tree.leaves(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg))) if leaf.size]
+    assert hlo.count('custom_call_target="tpu_custom_call"') \
+        == 3 * len(leaves)

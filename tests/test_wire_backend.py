@@ -316,13 +316,14 @@ def test_fused_pallas_lowering_matches_jnp(bits):
     np.testing.assert_allclose(float(jnp_rt.innovation_sq),
                                float(pls_rt.innovation_sq), rtol=1e-4,
                                atol=1e-6)
-    # payload layouts differ only in pad length: real code bytes agree
+    # payload layouts differ only in pad length and in the kernel's rows:
+    # real code bytes, read in order, agree
     cpb = 8 // bits
     for pj, pp, leaf in zip(jnp_rt.payload, pls_rt.payload,
                             jax.tree.leaves(g)):
         nbytes = leaf.size // cpb
         np.testing.assert_array_equal(np.asarray(pj[:nbytes]),
-                                      np.asarray(pp[:nbytes]))
+                                      np.asarray(pp).reshape(-1)[:nbytes])
 
 
 def test_dequant_acc_backends_match():

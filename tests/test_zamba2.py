@@ -379,7 +379,8 @@ def test_sharded_round_on_the_packed_wire():
     assert math.isfinite(o["loss"]) and o["uploads"] == W, o
     # every worker uploads in round 1: 4 bits a parameter and a radius a leaf
     assert o["bits"] == W * (32 * o["leaves"] + 4 * o["p"]), o
-    assert o["layout"] == {"flat": [o["leaves"], o["p"]], "axis": [0, 0]}, o
+    assert o["layout"] == {"flat": [o["leaves"], o["p"]], "axis": [0, 0],
+                           "quantize_passes": 1}, o
     assert o["shared_block"] == dict(
         heads=4, kv_heads=4, mlp_columns=96, ssm_heads=6, ssm_groups=1,
         invocations=2, blocks=2,
