@@ -1,15 +1,9 @@
-"""Paper supp: communication cost vs quantization bits b, plus the Pallas
-wire-kernel microbenchmark (us_per_call on this host; interpret mode on CPU —
-the number is a correctness-path latency, the TPU claim is structural)."""
+"""Paper supp: communication cost vs quantization bits b."""
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
 from repro.core import StrategyConfig, run_gradient_based
-from repro.kernels import dequant_acc, quantize_pack
 
-from .common import PAPER_CRITERION, logreg_init, logreg_loss, make_dataset, timed
+from .common import PAPER_CRITERION, logreg_init, logreg_loss, make_dataset
 
 
 def run(out_rows, results):
@@ -29,22 +23,6 @@ def run(out_rows, results):
         out_rows.append((f"bits_sweep_b{b}", float(r.cum_bits[-1]),
                          f"rounds={sweep[b]['rounds']};loss={sweep[b]['final_loss']:.2e}"))
     results["bits_sweep"] = sweep
-
-    # ---- wire kernel micro-bench ----
-    n = 1 << 20
-    g = jax.random.normal(jax.random.PRNGKey(0), (n,))
-    qh = jnp.zeros((n,))
-    R = jnp.max(jnp.abs(g))
-    for bits in (4, 8):
-        quantize_pack(g, qh, R, bits)  # compile
-        _, us = timed(lambda: jax.block_until_ready(quantize_pack(g, qh, R, bits)))
-        out_rows.append((f"kernel_quantize_pack_b{bits}_n1M", us, "interpret-mode us"))
-        pk, _ = quantize_pack(g, qh, R, bits)
-        pks = jnp.stack([pk] * 4)
-        Rs, keep = jnp.full((4,), R), jnp.ones((4,))
-        dequant_acc(pks, Rs, keep, bits, n)
-        _, us = timed(lambda: jax.block_until_ready(dequant_acc(pks, Rs, keep, bits, n)))
-        out_rows.append((f"kernel_dequant_acc_b{bits}_W4_n1M", us, "interpret-mode us"))
 
     checks = {"fewer bits per round with smaller b":
               sweep[2]["bits"] < sweep[4]["bits"] < sweep[8]["bits"]}

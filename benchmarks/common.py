@@ -3,8 +3,6 @@ regression; 1-hidden-layer ReLU network) on the synthetic MNIST-like mixture
 (the container is offline), M = 10 workers, paper hyperparameters."""
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 
@@ -66,10 +64,3 @@ def accuracy_nn(params, X, Y):
     h = jax.nn.relu(X @ params["w1"] + params["b1"])
     pred = jnp.argmax(h @ params["w2"] + params["b2"], -1)
     return float(jnp.mean((pred == jnp.argmax(Y, -1)).astype(jnp.float32)))
-
-
-def timed(fn, *args, **kw):
-    t0 = time.perf_counter()
-    out = fn(*args, **kw)
-    out = jax.block_until_ready(out) if hasattr(out, "block_until_ready") else out
-    return out, (time.perf_counter() - t0) * 1e6

@@ -6,12 +6,10 @@ results + claim checks to benchmarks/results/paper_repro.json.
 
 Flags:
 
-* ``--claims-only`` — run only the modules that gate paper claims (skips the
-  timing-only microbenchmarks, whose numbers are machine noise on CI).
+* ``--claims-only`` — run only the modules that gate paper claims; every
+  module does, so this is the default (the flag stays for CI's command).
 * ``--tiny`` — forward ``tiny=True`` to every module whose ``run`` accepts
-  it (shorter horizons / looser targets for CI smoke), and register the
-  ``wire_roofline`` pass: compiled cost analysis of the fused wire pipeline
-  (launch/roofline.py) with no timing, so it gates even on noisy runners.
+  it (shorter horizons / looser targets for CI smoke).
 
 Any module that *raises* fails the harness exactly like a failed claim: the
 exception is recorded as a synthetic failing check and the exit code is
@@ -28,14 +26,12 @@ import time
 import traceback
 
 
-def _modules(claims_only: bool, tiny: bool = False):
-    import types
-
+def _modules():
     from . import (adaptive_sweep, bits_sweep, convergence, ef_frontier,
                    fault_frontier, lasg_frontier, lm_frontier,
                    participation_frontier, serve_frontier, table2_gradient,
-                   table3_stochastic, wire_microbench)
-    mods = [("table2", table2_gradient), ("table3", table3_stochastic),
+                   table3_stochastic)
+    return [("table2", table2_gradient), ("table3", table3_stochastic),
             ("convergence", convergence), ("bits_sweep", bits_sweep),
             ("adaptive_sweep", adaptive_sweep),
             ("lasg_frontier", lasg_frontier),
@@ -43,25 +39,13 @@ def _modules(claims_only: bool, tiny: bool = False):
             ("ef_frontier", ef_frontier),
             ("fault_frontier", fault_frontier),
             ("lm_frontier", lm_frontier),
-            ("serve_frontier", serve_frontier),
-            ("wire_microbench", wire_microbench)]
-    if claims_only:
-        # timing-only modules: their checks are perf trajectories, not
-        # paper claims, and CI runners are too noisy to gate on them
-        mods = [(n, m) for n, m in mods if n != "wire_microbench"]
-    if tiny:
-        # roofline-only pass (compiled cost analysis, no timing): it is
-        # deterministic, so it can gate CI smoke even when the timing
-        # microbenchmark above is skipped
-        mods.append(("wire_roofline", types.SimpleNamespace(
-            run=wire_microbench.run_roofline)))
-    return mods
+            ("serve_frontier", serve_frontier)]
 
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--claims-only", action="store_true",
-                    help="only modules that gate paper claims")
+                    help="only modules that gate paper claims (all do)")
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke: forward tiny=True where supported")
     args = ap.parse_args(argv)
@@ -72,7 +56,7 @@ def main(argv=None) -> None:
     out_rows, results = [], {}
     all_checks = {}
 
-    for name, mod in _modules(args.claims_only, args.tiny):
+    for name, mod in _modules():
         t = time.time()
         kwargs = {}
         if args.tiny and "tiny" in inspect.signature(mod.run).parameters:

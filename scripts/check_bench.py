@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Benchmark-artifact gate: schema-validate every BENCH_*.json at the repo
-root (the per-PR artifacts CI uploads — BENCH_wire.json from the wire
-microbenchmark, BENCH_ef.json from the EF frontier, BENCH_faults.json
-from the fault frontier, BENCH_lm.json from the LM frontier,
-BENCH_serve.json from the serving frontier).  The REQUIRED set makes a
+root (the per-PR artifacts CI uploads — BENCH_ef.json from the EF
+frontier, BENCH_faults.json from the fault frontier, BENCH_lm.json from
+the LM frontier, BENCH_serve.json from the serving frontier).  The REQUIRED set makes a
 *missing* artifact fail too: a benchmark that silently stopped writing its
 file must not read as green.
 
@@ -17,17 +16,6 @@ Every artifact must be a JSON object with
 A ``false`` check is also a failure here: a committed artifact recording a
 failing claim must fail the gate, not ride along silently.
 
-``BENCH_wire.json`` additionally carries per-row lowering + roofline
-schema (the perf-trajectory contract): every row must record which
-lowering the fused pipeline measured (``fused_lowering``: "pallas" or
-"jnp-flat") and positive compiled cost-analysis roofline terms
-(``roofline_flops``, ``roofline_hbm_bytes``, a valid
-``roofline_bottleneck`` — "not measured" on a CPU row, which has no
-device peaks).  On a Pallas-capable backend (``jax_backend``
-!= "cpu") a row reporting "jnp-flat" fails the gate: the artifact would
-be silently measuring the fallback lowering on hardware where the
-kernels should run.
-
 Exit code 0 iff every artifact validates.
 
     python scripts/check_bench.py
@@ -40,8 +28,8 @@ import sys
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 SCALARS = (int, float, str, bool, type(None))
-REQUIRED = ("BENCH_wire.json", "BENCH_ef.json", "BENCH_faults.json",
-            "BENCH_lm.json", "BENCH_serve.json")
+REQUIRED = ("BENCH_ef.json", "BENCH_faults.json", "BENCH_lm.json",
+            "BENCH_serve.json")
 
 
 def validate(path: pathlib.Path) -> list[str]:
@@ -76,42 +64,6 @@ def validate(path: pathlib.Path) -> list[str]:
             elif v is False:
                 errors.append(f"{path.name}: checks[{name!r}] is false — "
                               f"artifact records a failing claim")
-
-    if path.name == "BENCH_wire.json" and isinstance(rows, list):
-        errors += validate_wire(path.name, doc, rows)
-    return errors
-
-
-_BOTTLENECKS = ("compute", "memory", "collective")
-
-
-def validate_wire(name: str, doc: dict, rows: list) -> list[str]:
-    """BENCH_wire.json-specific schema: per-row lowering + roofline terms."""
-    errors = []
-    backend = doc.get("jax_backend")
-    for i, row in enumerate(rows):
-        if not isinstance(row, dict):
-            continue
-        lowering = row.get("fused_lowering")
-        if lowering not in ("pallas", "jnp-flat"):
-            errors.append(f"{name}: rows[{i}] missing/invalid "
-                          f"'fused_lowering' (got {lowering!r})")
-        elif backend != "cpu" and lowering == "jnp-flat":
-            errors.append(f"{name}: rows[{i}] measured the jnp-flat "
-                          f"fallback on Pallas-capable backend "
-                          f"{backend!r} — kernels did not lower")
-        for key in ("roofline_flops", "roofline_hbm_bytes"):
-            v = row.get(key)
-            if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                    or v <= 0:
-                errors.append(f"{name}: rows[{i}] needs positive "
-                              f"{key!r}, got {v!r}")
-        valid = _BOTTLENECKS + (("not measured",) if backend == "cpu"
-                                 else ())
-        if row.get("roofline_bottleneck") not in valid:
-            errors.append(f"{name}: rows[{i}] 'roofline_bottleneck' must "
-                          f"be one of {valid}, got "
-                          f"{row.get('roofline_bottleneck')!r}")
     return errors
 
 

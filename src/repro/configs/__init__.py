@@ -1,17 +1,15 @@
 """Architecture registry: the 10 assigned configs + the paper's own models.
 
 ``get_config(arch_id)`` returns the exact published configuration;
-``for_shape(cfg, shape)`` applies shape-conditioned adjustments (sliding
-window for attention components at long_500k); ``smoke_config(cfg)`` returns
-the reduced variant used by the CPU smoke tests (<=2 layers, d_model<=512,
-<=4 experts).
+``smoke_config(cfg)`` returns the reduced variant used by the CPU smoke tests
+(<=2 layers, d_model<=512, <=4 experts).
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib
 
-from repro.models.config import INPUT_SHAPES, InputShape, ModelConfig
+from repro.models.config import ModelConfig
 
 _MODULES = {
     "zamba2-7b": "zamba2_7b",
@@ -28,24 +26,10 @@ _MODULES = {
 
 ARCH_IDS = tuple(_MODULES)
 
-LONG_CONTEXT_WINDOW = 8192
-
 
 def get_config(arch_id: str) -> ModelConfig:
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch_id]}")
     return mod.CONFIG
-
-
-def for_shape(cfg: ModelConfig, shape: InputShape) -> ModelConfig:
-    """Shape-conditioned config: attention components get a sliding-window
-    ring-buffer cache at long_500k (full 500k dense attention is skipped per
-    DESIGN.md; SSM components are O(1) in context natively)."""
-    if shape.name == "long_500k" and cfg.has_attention:
-        cfg = dataclasses.replace(cfg, sliding_window=LONG_CONTEXT_WINDOW)
-    if shape.kind == "train" and cfg.arch_type in ("dense", "vlm", "audio", "moe"):
-        # keep the flash q-chunk a divisor of seq everywhere
-        cfg = dataclasses.replace(cfg, q_chunk=min(cfg.q_chunk, shape.seq_len))
-    return cfg
 
 
 def smoke_config(cfg: ModelConfig) -> ModelConfig:

@@ -208,7 +208,7 @@ class MinibatchSource:
         return tree_sq_norm(jax.grad(self.global_loss)(params))
 
 
-def accumulate_loss_grads(loss_fn, params, microbatches, *, unroll=False):
+def accumulate_loss_grads(loss_fn, params, microbatches):
     """Fold ``(loss, grad)`` over a leading microbatch axis in one scan — the
     levanter ``accumulate_gradients_sharded`` idiom: per-microbatch
     ``value_and_grad`` with an f32 running *mean* (``acc + x / n``), so the
@@ -222,9 +222,7 @@ def accumulate_loss_grads(loss_fn, params, microbatches, *, unroll=False):
     add-zero and divide-by-one are identity in IEEE).  Shared by
     :class:`AccumulatingSource` and the sharded step's ``loss_and_grads``
     (launch/train.py), so both execution modes accumulate with identical
-    arithmetic.  ``unroll=True`` replays the fold as a Python loop (the
-    sharded step's probe mode, where scan bodies would be cost-counted
-    once).
+    arithmetic.
     """
     n = jax.tree_util.tree_leaves(microbatches)[0].shape[0]
 
@@ -237,12 +235,7 @@ def accumulate_loss_grads(loss_fn, params, microbatches, *, unroll=False):
 
     zero = (jnp.zeros((), jnp.float32),
             jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params))
-    if not unroll:
-        return jax.lax.scan(body, zero, microbatches)[0]
-    carry = zero
-    for i in range(n):
-        carry, _ = body(carry, jax.tree.map(lambda x: x[i], microbatches))
-    return carry
+    return jax.lax.scan(body, zero, microbatches)[0]
 
 
 class AccumulatingSource:

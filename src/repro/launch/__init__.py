@@ -1,2 +1,2 @@
-from .mesh import make_production_mesh, make_test_mesh, n_workers_of, worker_axes_of
+from .mesh import n_workers_of, worker_axes_of
 from .publish import ReplicaFleet, publish_trajectory, trainer_rounds

@@ -1,7 +1,8 @@
-"""Production meshes.
+"""The launch layer's meshes.
 
-A function, not a module-level constant: importing this module must never
-touch jax device state (the dry-run sets XLA_FLAGS before any jax init).
+Functions, not module-level constants: importing this module must never
+touch jax device state (a caller may still set XLA_FLAGS, e.g. forced host
+devices, before jax initialises).
 """
 from __future__ import annotations
 
@@ -30,17 +31,6 @@ def device_mesh(model: int = 1, n_devices: int | None = None):
     n = len(devices)
     return auto_axes(jax.make_mesh((n // model, model), ("data", "model"),
                                    devices=devices))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return auto_axes(jax.make_mesh(shape, axes))
-
-
-def make_test_mesh(data: int = 2, model: int = 2):
-    """Small mesh for CPU integration tests (requires forced host devices)."""
-    return auto_axes(jax.make_mesh((data, model), ("data", "model")))
 
 
 def worker_axes_of(mesh, *, hierarchical: bool = False):

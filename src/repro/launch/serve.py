@@ -1,8 +1,8 @@
 """Serving steps: prefill + single-token decode with sharded caches.
 
-``decode_32k`` / ``long_500k`` lower these (one new token against a KV cache
-of ``seq_len``), not train_step.  The KV cache sequence dim is sharded over
-the ``model`` axis (flash-decode); recurrent caches (mamba) shard heads.
+Decode runs one new token against a KV cache of ``max_len``.  The KV cache
+sequence dim is sharded over the ``model`` axis (flash-decode); recurrent
+caches (mamba) shard heads.
 """
 from __future__ import annotations
 

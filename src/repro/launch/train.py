@@ -14,9 +14,7 @@ skip criterion, and the aggregation collective is explicit:
   into uint8 payloads and exchanged with ``all_gather`` over the worker axes
   together with the per-worker radius R and skip mask; every device
   dequantizes and sums (the SPMD replica of the paper's server).  The
-  collective payload is physically b/32 of the float gradient — visible in
-  the dry-run HLO and the roofline collective term.  Pays off at pod
-  granularity (W=2) where the exchange crosses the slow DCN link.
+  collective payload is physically b/32 of the float gradient.
 
 Packed wire format (per worker, per round):
 
@@ -148,38 +146,16 @@ def _unsqueeze0(tree):
     return jax.tree.map(lambda x: x[None], tree)
 
 
-def _axis_size_static(worker_axes) -> int:
-    mesh = jax.sharding.get_abstract_mesh()
-    axes = (worker_axes,) if isinstance(worker_axes, str) else worker_axes
-    n = 1
-    for a in axes:
-        n *= dict(zip(mesh.axis_names, mesh.axis_sizes))[a]
-    return n
-
-
-def exchange_mode(n_workers: int) -> str:
-    """Which collective carries the packed payload across workers — a pure
-    function of the worker count:
-
-    * ``"gather"`` — all_gather payload + sidecars; every device decodes
-      and masked-sums all W payloads (the SPMD server replica).
-    * ``"permute"`` — W == 2 (pod pairs): one collective-permute payload
-      swap instead of a gather.
-    """
-    return "permute" if n_workers == 2 else "gather"
-
-
 def _flat_aggregate(payloads, radii, skip_mask, grads,
                     strategy: StrategyConfig, worker_axes):
     """The whole-leaf wire (fixed width, every leaf whole on its device):
     the exchange of the payloads and radii that the lazy rule's roundtrip
     already made (``worker_update(..., with_payload=True)``), so each leaf
     gets one radius pass and one pass-2 sweep a round.  Per leaf, the
-    payload and its radius are gathered over the workers at every W (at
-    W=2 a two-term sum is the same bits in either order, so the axis
-    codec's swap would buy nothing), and ``dequant_acc`` unpacks,
-    dequantizes and masked-sums the workers' payloads in one pass; no
-    leaf-shaped code array exists.  Returns the sum of innovations."""
+    payload and its radius are gathered over the workers, and
+    ``dequant_acc`` unpacks, dequantizes and masked-sums the workers'
+    payloads in one pass; no leaf-shaped code array exists.  Returns the
+    sum of innovations."""
     backend = get_backend(strategy.wire_backend)
     bits = strategy.effective_bits
     keep = jnp.logical_not(skip_mask).astype(jnp.float32)
@@ -238,38 +214,21 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
         provision = bits
     keep = jnp.logical_not(skip_mask).astype(jnp.float32)
     backend = get_backend(strategy.wire_backend)
-    n_workers = _axis_size_static(worker_axes)
-    mode = exchange_mode(n_workers)
-    use_gather = mode == "gather"
-    use_permute = mode == "permute"
     # per-round sidecars exchanged ONCE, outside the per-leaf loop (XLA does
     # not CSE collectives; a per-leaf exchange would issue one tiny
     # collective per parameter tensor)
-    _perm2 = [(0, 1), (1, 0)]
-    t_self = tau_of_selection(grid, onehot) if adaptive else None
-    if use_gather:
-        keep_w = jax.lax.all_gather(keep, worker_axes)              # [W]
-        if adaptive:
-            width_w = jax.lax.all_gather(width, worker_axes)        # [W] sidecar
-            tau_w = tau_of_width(grid, width_w)                     # [W]
-    if use_permute:
-        peer_keep = jax.lax.ppermute(keep, worker_axes, _perm2)
-        if adaptive:
-            t_peer = jax.lax.ppermute(t_self, worker_axes, _perm2)
+    keep_w = jax.lax.all_gather(keep, worker_axes)                  # [W]
+    if adaptive:
+        width_w = jax.lax.all_gather(width, worker_axes)            # [W] sidecar
+        tau_w = tau_of_width(grid, width_w)                         # [W]
 
     # the axis-packed payload codec lives in core/wire.py (one wire format
     # shared with the backend interface): pack 8/b codes per byte ALONG THE
     # LAST DIM (no flatten: a flatten of a model-sharded leaf forces GSPMD
     # to regather it, and at large meshes trips an XLA spmd_partitioner
     # assertion); indivisible last dims and provision 8 ship raw codes
-    def leaf_payload(q):
-        return pack_codes_along_axis(q, provision)
-
-    def leaf_unpack(payload, orig):
-        return unpack_codes_along_axis(payload, provision, orig)
-
     def gather_dequant_sum(q, R, orig, spec):
-        pl = leaf_payload(q)
+        pl = pack_codes_along_axis(q, provision)
         if spec is not None:
             pl = maybe_constrain(pl, *spec)
         payload = jax.lax.all_gather(pl, worker_axes)               # [W, ...]
@@ -277,7 +236,8 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
             payload = maybe_constrain(payload, None, *spec)
         Rw = jax.lax.all_gather(R, worker_axes)                     # [W]
         W = Rw.shape[0]
-        codes = jax.vmap(lambda p_: leaf_unpack(p_, orig))(payload)
+        codes = jax.vmap(
+            lambda p_: unpack_codes_along_axis(p_, provision, orig))(payload)
         if adaptive:
             t = tau_w.reshape((W,) + (1,) * orig.ndim)
         else:
@@ -288,33 +248,10 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
         delta = jnp.where(Rb > 0, delta, 0.0) * kb
         return jnp.sum(delta, axis=0)
 
-    def permute_dequant_sum(q, R, orig, spec):
-        # two-worker wire (pods): a single collective-permute payload
-        # exchange — p*b/8 bytes on the link, nothing for GSPMD to re-shard
-        pl = leaf_payload(q)
-        if spec is not None:
-            pl = maybe_constrain(pl, *spec)
-        peer_pl = jax.lax.ppermute(pl, worker_axes, _perm2)
-        peer_R = jax.lax.ppermute(R, worker_axes, _perm2)
-        if adaptive:
-            tv_self, tv_peer = t_self, t_peer
-        else:
-            tv_self = tv_peer = 1.0 / (2.0 ** provision - 1.0)
-
-        def dq(codes_pl, Rv, tv):
-            codes = leaf_unpack(codes_pl, orig).astype(jnp.float32)
-            d = 2.0 * tv * Rv * codes - Rv
-            return jnp.where(Rv > 0, d, 0.0)
-
-        return (dq(pl, R, tv_self) * keep
-                + dq(peer_pl, peer_R, tv_peer) * peer_keep)
-
     g_leaves, treedef = jax.tree_util.tree_flatten(grads)
     qh_leaves = jax.tree_util.tree_leaves(qhat)
     s_leaves = (jax.tree_util.tree_leaves(pspecs, is_leaf=lambda x: isinstance(x, tuple))
                 if pspecs is not None else [None] * len(g_leaves))
-    leaf_fn = {"gather": gather_dequant_sum,
-               "permute": permute_dequant_sum}[mode]  # permute: two workers
 
     # radius pre-pass: one scalar per leaf — the only whole-tree quantity.
     # The backend's pass-1 absmax primitive mirrors innovation() /
@@ -340,10 +277,9 @@ def _packed_aggregate(grads, qhat, skip_mask, strategy: StrategyConfig,
         if adaptive:
             q, delta_local = backend.leaf_quantize_adaptive(
                 g, qh, R, grid, onehot, t_sel)
-            agg = leaf_fn(q, R, g, spec)
         else:
             q, delta_local = backend.leaf_quantize(g, qh, R, bits)
-            agg = leaf_fn(q, R, g, spec)
+        agg = gather_dequant_sum(q, R, g, spec)
         q_new = qh.astype(jnp.float32) + delta_local
         return agg, q_new
 
@@ -474,16 +410,14 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
             microbatching identical for every evaluation point, via the
             engine-shared fold (core/engine.py accumulate_loss_grads, the
             same arithmetic AccumulatingSource runs in the simulated
-            engine).  Probe mode (unrolled layers) unrolls the microbatch
-            fold too so cost_analysis counts every pass."""
+            engine)."""
             with jax.named_scope(FWD_BWD):
                 if microbatch == 1:
                     return jax.value_and_grad(loss_fn)(at_params, batch)
                 mb = jax.tree.map(
                     lambda x: x.reshape((microbatch, x.shape[0] // microbatch)
                                         + x.shape[1:]), batch)
-                return accumulate_loss_grads(loss_fn, at_params, mb,
-                                             unroll=not cfg.scan_layers)
+                return accumulate_loss_grads(loss_fn, at_params, mb)
 
         loss, grads = loss_and_grads(params)
         # The wire reads the gradient as its dtype holds it.  Without the
@@ -643,7 +577,7 @@ def make_train_step(cfg: ModelConfig, mesh, strategy: StrategyConfig,
 
 
 # ---------------------------------------------------------------------------
-# State construction (concrete and abstract/dry-run variants)
+# State construction (concrete and abstract variants)
 # ---------------------------------------------------------------------------
 
 def init_train_state(key, cfg: ModelConfig, mesh, strategy: StrategyConfig,
@@ -661,7 +595,8 @@ def init_train_state(key, cfg: ModelConfig, mesh, strategy: StrategyConfig,
 def train_state_specs(cfg: ModelConfig, mesh, strategy: StrategyConfig,
                       optimizer: Optimizer, worker_axes):
     """Abstract TrainState of ShapeDtypeStructs with NamedShardings attached —
-    lowers without allocating (the multi-pod dry-run path)."""
+    lowers without allocating (a compile for a described chip, the
+    benchmark's shardings)."""
     mesh = auto_axes(mesh)
     from repro.models import init_params
     from repro.core.strategy import init_comm_state

@@ -1,4 +1,4 @@
-from .config import INPUT_SHAPES, InputShape, ModelConfig, n_active_params, n_params
+from .config import ModelConfig, n_params
 from .model import (decode_step, forward, init_cache, init_params, lm_loss,
-                    lm_worker_loss, make_batch_specs, prefill)
+                    lm_worker_loss, prefill)
 from .sharding import cache_pspecs, param_pspecs

@@ -13,8 +13,7 @@ partial-reduction collectives.  This sidesteps the ``kv_heads < model-axis``
 divisibility trap (e.g. 8 KV heads on a 16-way model axis).
 
 Sliding-window decode (``cfg.sliding_window > 0``) uses a ring-buffer cache of
-``window`` slots — this is what makes ``long_500k`` lowerable for the
-full-attention architectures.
+``window`` slots, so the cache stays bounded at any context length.
 """
 from __future__ import annotations
 
@@ -121,18 +120,9 @@ def chunked_causal_attention(q, k, v, q_positions, kv_positions,
 def attention_forward(p, x, positions, cfg: ModelConfig, *, return_kv: bool = False,
                       scale: float = 0.0):
     """Train/prefill path. x:[B,S,d_in]; positions:[S]."""
-    from .layers import maybe_constrain
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, positions, cfg)
-    if cfg.attn_batch_shard:
-        # heads indivisible by the model axis: shard the (local) batch over
-        # it instead, shrinking every attention transient by the axis size
-        q = maybe_constrain(q, "model", None, None, None)
-        k = maybe_constrain(k, "model", None, None, None)
-        v = maybe_constrain(v, "model", None, None, None)
     o = chunked_causal_attention(q, k, v, positions, positions, cfg, scale)
-    if cfg.attn_batch_shard:
-        o = maybe_constrain(o, None, None, None, None)
     out = o.reshape(B, S, cfg.n_heads * cfg.hd) @ p["wo"]
     if return_kv:
         return out, (k, v)

@@ -1,8 +1,8 @@
-"""Model & input-shape configuration.
+"""Model configuration.
 
-Every assigned architecture is expressed as a :class:`ModelConfig`; the four
-assigned input shapes as :class:`InputShape`.  ``repro/configs/<arch>.py``
-instantiates the exact published numbers and registers them.
+Every assigned architecture is expressed as a :class:`ModelConfig`.
+``repro/configs/<arch>.py`` instantiates the exact published numbers and
+registers them.
 """
 from __future__ import annotations
 
@@ -31,17 +31,11 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0               # per-expert hidden dim
-    moe_groups_per_shard: int = 8   # token sub-groups per data shard (capacity locality)
     capacity_factor: float = 1.25
-    # batch-sharded attention: when the head count is indivisible by the
-    # model axis (attention weights replicated), reshard the *local batch*
-    # over the model axis for the attention block so score/softmax transients
-    # shrink by the axis size. Costs a [B,S,D] reshard in/out per layer.
-    attn_batch_shard: bool = False
     # combine implementation: "gather" (baseline: per-token gather from the
     # expert-sharded buffer -> GSPMD all-gathers E*C*D) or "scatter" (expert-
     # side scatter-add -> GSPMD partial-scatters locally and all-reduces only
-    # T*D — the optimal combine payload; see EXPERIMENTS.md §Perf).
+    # T*D — the optimal combine payload).
     moe_combine: str = "gather"
     # ssm (mamba2)
     ssm_state: int = 0
@@ -73,12 +67,6 @@ class ModelConfig:
     # without it the backward pass stores every intra-layer intermediate of
     # every layer (e.g. the SSD chunk tensors), far beyond HBM.
     remat: bool = True
-    # scan-over-layers (single HLO layer body; fast 512-device compiles).
-    # False unrolls the stack in python — used by the roofline probes because
-    # XLA's cost_analysis counts a while-loop body ONCE regardless of trip
-    # count (verified empirically), so exact FLOP/byte/collective counts need
-    # an unrolled lowering (done at reduced depth and extrapolated).
-    scan_layers: bool = True
     # frontends ([vlm]/[audio]): token ids are precomputed codebook ids (stub
     # per the carve-out); the backbone consumes ids like any LM.
     frontend: Optional[str] = None  # "vq_image" | "encodec" | None
@@ -105,33 +93,8 @@ class ModelConfig:
     def conv_channels(self) -> int:
         return self.d_inner + 2 * self.ssm_n_groups * self.ssm_state
 
-    @property
-    def has_attention(self) -> bool:
-        return self.n_heads > 0
-
-    @property
-    def is_recurrent(self) -> bool:
-        """O(1)-in-seq decode state (SSM/hybrid) => long_500k is native."""
-        return self.arch_type in ("ssm", "hybrid")
-
     def padded_vocab(self, multiple: int = 16) -> int:
         return ((self.vocab + multiple - 1) // multiple) * multiple
-
-
-@dataclasses.dataclass(frozen=True)
-class InputShape:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                       # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES = {
-    "train_4k":    InputShape("train_4k",    4_096,   256, "train"),
-    "prefill_32k": InputShape("prefill_32k", 32_768,  32,  "prefill"),
-    "decode_32k":  InputShape("decode_32k",  32_768,  128, "decode"),
-    "long_500k":   InputShape("long_500k",   524_288, 1,   "decode"),
-}
 
 
 def n_params(cfg: ModelConfig) -> int:
@@ -176,13 +139,3 @@ def shared_block_params(cfg: ModelConfig) -> int:
     D, hd = cfg.d_model, cfg.hd
     attn = 2 * D * (cfg.n_heads + 2 * cfg.n_kv_heads) * hd + cfg.n_heads * hd * D
     return 2 * D + attn + D + 3 * D * cfg.d_ff
-
-
-def n_active_params(cfg: ModelConfig) -> int:
-    """Active parameters per token (MoE: top_k of n_experts)."""
-    if not cfg.n_experts:
-        return n_params(cfg)
-    D = cfg.d_model
-    dense_moe = cfg.n_layers * (D * cfg.n_experts + cfg.n_experts * 3 * D * cfg.moe_d_ff)
-    active_moe = cfg.n_layers * (D * cfg.n_experts + cfg.top_k * 3 * D * cfg.moe_d_ff)
-    return n_params(cfg) - dense_moe + active_moe

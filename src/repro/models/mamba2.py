@@ -4,8 +4,7 @@ Implements the SSD algorithm of Dao & Gu 2024 (arXiv:2405.21060): the
 sequence is split into chunks; within a chunk the recurrence is evaluated as
 a (masked, decay-weighted) attention-like quadratic form; across chunks a
 linear ``lax.scan`` carries the [H, P, N] SSM state.  Decode is a single
-recurrent state update — O(1) in context length, which is what makes
-``long_500k`` native for the ssm/hybrid architectures.
+recurrent state update — O(1) in context length.
 
 Projection weights are kept as *separate* tensors per stream (z / x / B / C /
 dt) rather than one fused ``in_proj`` so each can carry its own sharding

@@ -45,14 +45,6 @@ def lm_worker_loss(cfg: ModelConfig, n_workers: int):
     return loss_fn
 
 
-def make_batch_specs(cfg: ModelConfig, batch: int, seq: int):
-    """ShapeDtypeStructs for one global training batch."""
-    return {
-        "tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32),
-        "targets": jax.ShapeDtypeStruct((batch, seq), jnp.int32),
-    }
-
-
 init_params = stack.init_params
 forward = stack.forward
 prefill = stack.prefill

@@ -11,8 +11,8 @@ Two paths:
 * **capacity path** (train / prefill, S > 1): tokens grouped per batch row,
   per-expert capacity C = Tg * top_k / E * capacity_factor, overflow dropped
   (standard GShard/Switch semantics).  The combine gather over the
-  expert-sharded buffer lowers to an all-gather over ``model`` under GSPMD —
-  that collective is the MoE hillclimb target in EXPERIMENTS.md §Perf.
+  expert-sharded buffer lowers to an all-gather over ``model`` under GSPMD
+  (``ModelConfig.moe_combine="scatter"`` replaces it with an all-reduce).
 * **dense path** (decode, S == 1): every local expert is applied to every
   token and the result masked-combined with a contraction over the sharded
   expert dim (an all-reduce). With a handful of tokens per device the expert
@@ -34,9 +34,8 @@ def _shard_experts(x, spec):
 
     Without this GSPMD re-shards the f32 *cotangents* of the dispatch/expert
     buffers to replicated inside the remat backward — an all-gather of
-    E*C*D f32 per layer (measured: 2x 5 GiB/layer on qwen3-moe train_4k; see
-    EXPERIMENTS.md §Perf). Constraints on the forward values propagate to the
-    cotangents.
+    E*C*D f32 per layer (measured: 2x 5 GiB/layer on qwen3-moe train_4k).
+    Constraints on the forward values propagate to the cotangents.
     """
     try:
         mesh = jax.sharding.get_abstract_mesh()
@@ -71,7 +70,7 @@ def _make_dispatch(S: int, dtype_name: str):
     an all-gather of E*C*D f32 per layer (measured: 2x 5 GiB/layer on
     qwen3-moe train_4k). Writing the transpose ourselves in the batched
     .at[].add form lowers to partial scatters + one all-reduce of [B,S,D]
-    (the pattern GSPMD gets right; see EXPERIMENTS.md §Perf).
+    (the pattern GSPMD gets right).
     """
     dtype = jnp.dtype(dtype_name)
 

@@ -5,8 +5,8 @@ divisibility guard: a dim is sharded only when the *semantic* unit count
 (heads, experts, ff, inner) divides the model-axis size; otherwise it is
 replicated.  This is what lets e.g. mamba2-130m (24 SSM heads) or
 musicgen-medium (24 attention heads) lower on a 16-way model axis — small
-models simply don't tensor-parallelize, and that is recorded per-arch in the
-dry-run output rather than papered over with silent resharding.
+models simply don't tensor-parallelize, rather than being resharded
+silently.
 """
 from __future__ import annotations
 

@@ -2,8 +2,7 @@
 
 Layers are *stacked*: block parameters carry a leading layer dim and the
 forward is a single ``lax.scan`` over it (MaxText-style), so the HLO contains
-one layer body regardless of depth — essential to keep 36-54-layer models
-compilable on the 512-device dry-run meshes.
+one layer body regardless of depth and compile time does not grow with it.
 
 Hybrid stacks are Zamba2's (arXiv:2411.15242; the layer equations of
 ``transformers``' ``Zamba2ForCausalLM``).  Every layer is a Mamba2 layer
@@ -156,7 +155,7 @@ def _hybrid_stack(params, x, cfg: ModelConfig, layer, mcache=None, kv=None):
     ``j`` and its KV cache (``None`` without one); it returns ``(x, mc_new,
     kv_new)``.  ``mcache`` (``kv``) is a per-layer (per-invocation) tree
     with a leading axis, or ``None``.  Runs of Mamba layers are one scan
-    each (unrolled where ``scan_layers`` is off).  Returns ``x``, the new
+    each.  Returns ``x``, the new
     per-layer tree (or ``None``) and the invocations' KV in order."""
     blocks = params["blocks"]
     out_m, out_kv = [], []
@@ -167,20 +166,11 @@ def _hybrid_stack(params, x, cfg: ModelConfig, layer, mcache=None, kv=None):
     def run(x, lo, hi):
         if hi <= lo:
             return x
-        bps, mcs = part(blocks, lo, hi), part(mcache, lo, hi)
-        if cfg.scan_layers:
-            def body(x, xs):
-                x, mc, _ = layer(xs[0], x, xs[1], None)
-                return x, mc
-            x, mcs_new = jax.lax.scan(body, x, (bps, mcs))
-        else:
-            got = []
-            for i in range(hi - lo):
-                x, mc, _ = layer(_layer_slice(bps, i), x,
-                                 None if mcs is None else _layer_slice(mcs, i), None)
-                got.append(mc)
-            mcs_new = (None if mcache is None
-                       else jax.tree.map(lambda *a: jnp.stack(a), *got))
+        def body(x, xs):
+            x, mc, _ = layer(xs[0], x, xs[1], None)
+            return x, mc
+        x, mcs_new = jax.lax.scan(body, x, (part(blocks, lo, hi),
+                                            part(mcache, lo, hi)))
         out_m.append(mcs_new)
         return x
 
@@ -232,8 +222,6 @@ def forward(params, tokens, cfg: ModelConfig):
 
         x, _, _ = _hybrid_stack(params, x, cfg, layer)
         aux = jnp.zeros((), jnp.float32)
-    elif not cfg.scan_layers:
-        x, aux = _forward_unrolled(params, x, positions, cfg, maybe_remat)
     elif cfg.arch_type in ("dense", "moe", "vlm", "audio"):
         @maybe_remat
         def layer(x, bp):
@@ -263,25 +251,6 @@ def forward(params, tokens, cfg: ModelConfig):
 
 def _layer_slice(tree, i):
     return jax.tree.map(lambda a: a[i], tree)
-
-
-def _forward_unrolled(params, x, positions, cfg: ModelConfig, maybe_remat):
-    """Python-unrolled stack (exact cost_analysis for roofline probes)."""
-    aux = jnp.zeros((), jnp.float32)
-    for i in range(cfg.n_layers):
-        bp = _layer_slice(params["blocks"], i)
-        if cfg.arch_type in ("dense", "moe", "vlm", "audio"):
-            def layer(x, bp=bp):
-                y, a, _ = attn_block_fwd(bp, x, positions, cfg)
-                return y, a
-            x, a = maybe_remat(layer)(x)
-            aux = aux + a
-        else:
-            def layer(x, bp=bp):
-                y, _ = mamba_block_fwd(bp, x, cfg)
-                return y
-            x = maybe_remat(layer)(x)
-    return x, aux
 
 
 # ---------------------------------------------------------------------------
@@ -337,8 +306,6 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int):
         if kvs:
             cache["attn"] = {"k": jnp.stack([k for k, _ in kvs]),
                              "v": jnp.stack([v for _, v in kvs])}
-    elif not cfg.scan_layers:
-        x, cache = _prefill_unrolled(params, x, positions, cfg, cache, place_kv)
     elif cfg.arch_type in ("dense", "moe", "vlm", "audio"):
         def body(carry, bp):
             x, aux = carry
@@ -359,30 +326,6 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int):
     x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(cfg.compute_dtype)).astype(jnp.float32)
     return logits, cache
-
-
-def _prefill_unrolled(params, x, positions, cfg: ModelConfig, cache, place_kv):
-    """Python-unrolled prefill (roofline probes)."""
-    attn_k, attn_v, states, tx, tB, tC = [], [], [], [], [], []
-    for i in range(cfg.n_layers):
-        bp = _layer_slice(params["blocks"], i)
-        if cfg.arch_type in ("dense", "moe", "vlm", "audio"):
-            x, _, kv = attn_block_fwd(bp, x, positions, cfg, return_kv=True)
-            k, v = place_kv(kv)
-            attn_k.append(k)
-            attn_v.append(v)
-        else:
-            x, (st, tail) = mamba_block_fwd(bp, x, cfg, return_state=True)
-            states.append(st)
-            tx.append(tail["x"])
-            tB.append(tail["B"])
-            tC.append(tail["C"])
-    if attn_k:
-        cache["attn"] = {"k": jnp.stack(attn_k), "v": jnp.stack(attn_v)}
-    if states:
-        cache["mamba"] = {"ssm": jnp.stack(states), "conv_x": jnp.stack(tx),
-                          "conv_B": jnp.stack(tB), "conv_C": jnp.stack(tC)}
-    return x, cache
 
 
 def decode_step(params, cache, tokens, cfg: ModelConfig):
@@ -413,8 +356,6 @@ def decode_step(params, cache, tokens, cfg: ModelConfig):
         if kvs:
             new_cache["attn"] = {"k": jnp.stack([k for k, _ in kvs]),
                                  "v": jnp.stack([v for _, v in kvs])}
-    elif not cfg.scan_layers:
-        x, new_cache = _decode_unrolled(params, cache, x, pos, cfg)
     elif cfg.arch_type in ("dense", "moe", "vlm", "audio"):
         def body(x, xs):
             bp, ck, cv = xs
@@ -444,35 +385,3 @@ def decode_step(params, cache, tokens, cfg: ModelConfig):
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(cfg.compute_dtype)).astype(jnp.float32)
     return logits, new_cache
-
-
-def _decode_unrolled(params, cache, x, pos, cfg: ModelConfig):
-    """Python-unrolled decode step (roofline probes)."""
-    new_cache = dict(cache)
-    ks, vs, mslices = [], [], []
-    for i in range(cfg.n_layers):
-        bp = _layer_slice(params["blocks"], i)
-        if cfg.arch_type in ("dense", "moe", "vlm", "audio"):
-            ck = cache["attn"]["k"][i]
-            cv = cache["attn"]["v"][i]
-            h, nk, nv = decode_attention(bp["attn"],
-                                         rms_norm(x, bp["ln1"], cfg.norm_eps),
-                                         ck, cv, pos, cfg)
-            x = x + h
-            h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
-            m = (moe_forward_dense(bp["moe"], h2, cfg)[0] if "moe" in bp
-                 else swiglu(h2, **bp["mlp"]))
-            x = x + m
-            ks.append(nk)
-            vs.append(nv)
-        else:
-            cs = _layer_slice(cache["mamba"], i)
-            h, nc = mamba2_decode(bp["mamba"], rms_norm(x, bp["ln"], cfg.norm_eps),
-                                  cs, cfg)
-            x = x + h
-            mslices.append(nc)
-    if ks:
-        new_cache["attn"] = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
-    if mslices:
-        new_cache["mamba"] = jax.tree.map(lambda *xs: jnp.stack(xs), *mslices)
-    return x, new_cache

@@ -12,6 +12,8 @@ some of whose last dims are odd (three SSD heads), and checks:
   exchange with 4 workers at 2, 4 and 8 bits and with 2 workers at 4;
   and for the fused backend's Pallas kernels (interpret mode) on the
   gather at 4;
+* the axis codec (a ``model=2`` mesh) is the float wire bitwise with 2
+  workers at 4 bits, for both backends (it gathers at every W);
 * the step's layout counter reads every leaf flat on a ``model=1`` mesh,
   and none on a ``model=2`` mesh or with an adaptive schedule;
 * at trace time, counters wrapped round the backends' radius and pass-2
@@ -37,6 +39,11 @@ BACKENDS = ("reference", "fused")
 MESHES = [(4, b) for b in BITS] + [(2, 4)]
 CASES = [(W, backend, b) for W, b in MESHES for backend in BACKENDS]
 IDS = [f"W{W}-{backend}-b{b}" for W, backend, b in CASES]
+# (workers, model) meshes on which the axis codec's packed wire is held to
+# the float wire
+AXIS_MESHES = [(2, 2)]
+AXIS_CASES = [(W, M, backend) for W, M in AXIS_MESHES for backend in BACKENDS]
+AXIS_IDS = [f"W{W}-model{M}-{backend}" for W, M, backend in AXIS_CASES]
 # (workers, model) meshes whose traced step the pass counters read
 PASS_MESHES = [(4, 1), (1, 1), (4, 2)]
 
@@ -55,7 +62,7 @@ from repro.launch.train import (init_train_state, make_train_step,
 from repro.models import init_params
 from repro.optim import sgd
 
-MESHES, BACKENDS, PASS_MESHES = json.loads(sys.argv[1])
+MESHES, AXIS_MESHES, BACKENDS, PASS_MESHES = json.loads(sys.argv[1])
 cfg = dataclasses.replace(smoke_config(get_config("mamba2-130m")),
                           n_layers=1, d_model=48, vocab=256)
 opt = sgd()
@@ -98,6 +105,15 @@ for W, bits in MESHES:
         got, step = run(mesh, base._replace(wire_backend=backend), "packed")
         out[f"parity-W{W}-{backend}-b{bits}"] = max_param_diff(want, got)
         out[f"layout-W{W}-{backend}-b{bits}"] = step.wire_layout
+
+# the axis codec: model-sharded leaves, one exchange at every W
+for W, M in AXIS_MESHES:
+    mesh = mesh_of(W, M)
+    base = StrategyConfig(kind="laq", bits=4, per_leaf_radius=True)
+    want, _ = run(mesh, base, "float")
+    for backend in BACKENDS:
+        got, _ = run(mesh, base._replace(wire_backend=backend), "packed")
+        out[f"axis-parity-W{W}-model{M}-{backend}"] = max_param_diff(want, got)
 
 # the fused backend's kernels (Pallas interpret mode) in the step: the
 # float wire on the same backend is the reference, since its moments are
@@ -181,7 +197,7 @@ def result():
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, "-c", _SCRIPT,
-         json.dumps([MESHES, BACKENDS, PASS_MESHES])],
+         json.dumps([MESHES, AXIS_MESHES, BACKENDS, PASS_MESHES])],
         env=env, capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stderr[-4000:]
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
@@ -198,6 +214,11 @@ def test_model_has_odd_and_unblocked_leaves(result):
 @pytest.mark.parametrize("W,backend,bits", CASES, ids=IDS)
 def test_packed_equals_float_bitwise(result, W, backend, bits):
     assert result[f"parity-W{W}-{backend}-b{bits}"] == 0.0
+
+
+@pytest.mark.parametrize("W,model,backend", AXIS_CASES, ids=AXIS_IDS)
+def test_axis_codec_equals_float_bitwise(result, W, model, backend):
+    assert result[f"axis-parity-W{W}-model{model}-{backend}"] == 0.0
 
 
 def test_packed_equals_float_on_the_kernels(result):

@@ -296,15 +296,3 @@ def test_laq_step_arch_smokes_subprocess():
         expected = W * (32 * o["n_leaves"] + 4 * o["p"])
         assert o["total_bits"] == float(expected), (arch, o, expected)
 
-
-def test_long_context_configs():
-    """for_shape applies the sliding window to attention archs at long_500k."""
-    from repro.configs import for_shape
-    from repro.models.config import INPUT_SHAPES
-    shp = INPUT_SHAPES["long_500k"]
-    dense = for_shape(get_config("qwen3-8b"), shp)
-    assert dense.sliding_window == 8192
-    ssm = for_shape(get_config("mamba2-130m"), shp)
-    assert ssm.sliding_window == 0          # recurrent: native long context
-    hyb = for_shape(get_config("zamba2-7b"), shp)
-    assert hyb.sliding_window == 8192       # shared attn block needs the ring

@@ -282,14 +282,6 @@ print("RESULT " + json.dumps(out))
 """
 
 
-def test_exchange_mode_native():
-    """gather for W > 2, one permute swap for pod pairs."""
-    from repro.launch.train import exchange_mode
-    assert exchange_mode(2) == "permute"
-    assert exchange_mode(4) == "gather"
-    assert exchange_mode(8) == "gather"
-
-
 @pytest.mark.slow
 def test_sharded_integration_subprocess():
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))

@@ -48,7 +48,7 @@ SMALL = dict(name="zamba2-small", arch_type="hybrid", n_layers=4, d_model=64,
              ssm_n_groups=1, ssm_n_heads=6, ssm_chunk=16, hybrid_layers=[1, 3],
              num_mem_blocks=2, adapter_rank=8, rope_theta=10000.0,
              norm_eps=1e-5, q_chunk=32, kv_chunk=16, param_dtype="float32",
-             compute_dtype="float32", remat=True, scan_layers=True)
+             compute_dtype="float32", remat=True)
 
 
 def program_config(m):
